@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-import repro  # noqa: F401  (installs jax compat shims)
 from benchmarks.bench_util import emit, time_fn
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

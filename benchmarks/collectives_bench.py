@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-import repro  # noqa: F401  (installs jax compat shims)
 from benchmarks.bench_util import emit, time_fn
 from benchmarks.hlo_cost import (allreduce_wire_bytes, analyze_text,
                                  collective_seconds)

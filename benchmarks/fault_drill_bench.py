@@ -50,7 +50,6 @@ import time
 
 import numpy as np
 
-import repro  # noqa: F401  (installs jax compat shims)
 from benchmarks.bench_util import emit
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -31,7 +31,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-import repro  # noqa: F401  (installs jax compat shims)
 from benchmarks.bench_util import emit
 from repro.configs.base import ModelConfig
 from repro.core import memory as mem_mod

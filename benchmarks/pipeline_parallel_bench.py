@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-import repro  # noqa: F401  (installs jax compat shims)
 from benchmarks.bench_util import emit, time_fn
 from benchmarks.hlo_cost import (analyze_text, pipeline_boundary_wire_bytes,
                                  pipeline_bubble_fraction)

@@ -26,8 +26,11 @@
                 (8 fake devices)
   roofline      §Roofline summary from the dry-run artifacts (if present)
 
-Prints ``name,us_per_call,derived`` CSV.  Multi-device sections re-exec in
-a child with 8 fake host devices so this process keeps the real topology.
+Prints ``name,us_per_call,derived`` CSV.  Every section is CPU emulation:
+this harness sets ``JAX_PLATFORMS=cpu`` for itself and its children, even
+on a TPU host, so none of its numbers is a chip number (``chip_smoke.py``
+is what runs on the chip).  Multi-device sections re-exec in a child with
+8 fake host devices.
 """
 
 from __future__ import annotations
@@ -35,6 +38,10 @@ from __future__ import annotations
 import os
 import subprocess
 import sys
+
+# before any section imports jax: this process and its children stay on
+# the CPU (a parent holding a TPU would also starve the children of it)
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 MULTIDEV = {"gemm": "benchmarks.gemm_layouts",
             "compression": "benchmarks.compression_bench",

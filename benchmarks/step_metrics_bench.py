@@ -17,7 +17,6 @@ from __future__ import annotations
 import json
 import os
 
-import repro  # noqa: F401  (installs jax compat shims)
 from benchmarks.bench_util import emit
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

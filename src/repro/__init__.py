@@ -1,10 +1,1 @@
-"""repro — a JAX reproduction of dMath (distributed linear algebra for DL).
-
-Importing the package installs the JAX version-compat shims (see
-:mod:`repro.compat`) so one source tree runs on both current and older
-JAX runtimes.
-"""
-
-from repro import compat as _compat
-
-_compat.install()
+"""repro — a JAX reproduction of dMath (distributed linear algebra for DL)."""

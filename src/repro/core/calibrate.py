@@ -2,7 +2,7 @@
 
 Every alpha/beta/bandwidth/FLOPs/HBM constant the planner consumes was
 hand-set to a nominal accelerator value (``comms/topology.py`` LinkSpecs,
-``pipeline/costs.py`` DEVICE_FLOPS, the ``core/memory.py`` footprint
+the ``core/chips.py`` peak FLOPs, the ``core/memory.py`` footprint
 model) — and the PR-6 drift report proved how far nominal is from this
 machine: ``step_time_s`` at 557x drift.  This module closes the loop the
 ROADMAP names (PolyDL's generate/measure/let-data-pick pattern, with

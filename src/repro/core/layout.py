@@ -197,9 +197,9 @@ def constrain(x: jax.Array, layout: Layout, mesh: Optional[Mesh] = None):
     is a no-op.  This is what lets model code that annotates layouts run
     unchanged under the explicit comms schedules in :mod:`repro.comms`.
     """
-    from repro.compat import bound_axis_names
+    from repro.core.manual import manual_axes
 
-    manual = bound_axis_names()
+    manual = manual_axes()
     if manual:
         for name in set(layout.mesh_axes_used()) & manual:
             layout = layout.drop_axis(name)

@@ -27,9 +27,9 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 
+from . import chips
+from .chips import GIB
 from .layout import Layout
-
-GIB = 1024**3
 
 #: The single headroom constant: fraction of physical HBM the footprint
 #: model may plan into.  The remainder covers the XLA arena slop, compiler
@@ -64,30 +64,14 @@ class MemoryBudget:
                 f"@ headroom {self.headroom:.2f})")
 
 
-#: Platform-keyed per-chip budgets.  ``cpu`` is the debug stand-in used by
-#: the fake-device test meshes — kept at v5e parity so CPU dry-runs answer
-#: the question "would this fit a v5e?".
+#: Per-chip budgets, one per entry of :data:`repro.core.chips.CHIPS`
+#: (``cpu`` is the fake-device test stand-in, kept at v5e parity).
 HBM_BUDGETS: Dict[str, MemoryBudget] = {
-    "v5e": MemoryBudget(16 * GIB, platform="v5e"),
-    "v5p": MemoryBudget(95 * GIB, platform="v5p"),
-    "h100": MemoryBudget(80 * GIB, platform="h100"),
-    "cpu": MemoryBudget(16 * GIB, platform="cpu"),
-}
+    name: MemoryBudget(c.hbm_bytes, platform=name)
+    for name, c in chips.CHIPS.items()}
 
+#: budget of analytic planning that names no device (no mesh, no platform)
 DEFAULT_PLATFORM = "v5e"
-
-#: kept for backward compatibility — prefer ``HBM_BUDGETS["v5e"]``.
-HBM_BYTES_V5E = HBM_BUDGETS["v5e"].hbm_bytes
-
-# device_kind substring -> budget key, first match wins (order matters:
-# "v5p" must be probed before the bare "v5"/"v5 lite" forms).
-_KIND_TABLE = (
-    ("v5p", "v5p"),
-    ("v5e", "v5e"),
-    ("v5 lite", "v5e"),
-    ("h100", "h100"),
-    ("cpu", "cpu"),
-)
 
 
 def budget_for(mesh=None, *, hbm_gib: Optional[float] = None,
@@ -96,7 +80,9 @@ def budget_for(mesh=None, *, hbm_gib: Optional[float] = None,
     """Resolve the per-device budget for a mesh.
 
     Priority: explicit ``hbm_gib`` override (the ``--hbm-gib`` flag) >
-    explicit ``platform`` key > the mesh's device kind > the v5e default.
+    explicit ``platform`` key > the mesh's device kind > the v5e default
+    when neither a platform nor a mesh is given.  An unknown platform or
+    device kind raises: no device silently gets another one's budget.
     """
     if hbm_gib is not None:
         return MemoryBudget(int(hbm_gib * GIB),
@@ -105,16 +91,12 @@ def budget_for(mesh=None, *, hbm_gib: Optional[float] = None,
                             platform=platform or "override")
     key = platform
     if key is None and mesh is not None:
-        try:
-            kind = mesh.devices.flat[0].device_kind.lower()
-        except (AttributeError, IndexError):
-            kind = ""
-        for sub, k in _KIND_TABLE:
-            if sub in kind:
-                key = k
-                break
-    base = HBM_BUDGETS.get(key or DEFAULT_PLATFORM,
-                           HBM_BUDGETS[DEFAULT_PLATFORM])
+        key = chips.chip_key(mesh.devices.flat[0].device_kind)
+    key = key or DEFAULT_PLATFORM
+    if key not in HBM_BUDGETS:
+        raise ValueError(f"no HBM budget for platform {key!r}; known: "
+                         f"{sorted(HBM_BUDGETS)}")
+    base = HBM_BUDGETS[key]
     if headroom is not None and headroom != base.headroom:
         return dataclasses.replace(base, headroom=headroom)
     return base

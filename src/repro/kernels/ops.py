@@ -8,11 +8,11 @@ the algorithm; the asterisked results show the fallback firing).
 Two gates sit between a call and a fused kernel:
 
 1. **availability** — :func:`pallas_supported` probes ONCE whether a tiny
-   Pallas kernel actually lowers and runs on this backend.  A requested
-   ``pallas`` mode silently demotes to ``ref`` when the probe fails
-   (lowering errors cannot be caught inside an outer jit trace, so the
-   decision must happen before tracing) and the demotion is counted in
-   ``repro.obs`` (``kernels.fallback.*``).
+   Pallas kernel actually lowers and runs on this backend (lowering
+   errors cannot be caught inside an outer jit trace, so the decision
+   must happen before tracing).  On the CPU a requested ``pallas`` mode
+   demotes to ``ref`` when the probe fails, counted in ``repro.obs``
+   (``kernels.fallback.*``); on a TPU backend the failure raises.
 2. **roofline** — :mod:`repro.kernels.roofline` decides per call-shape
    whether the fusion pays: fused kernels win on memory-bound shapes by
    eliminating HBM round trips; on compute-bound shapes XLA's reference
@@ -23,7 +23,9 @@ record which fused kernels were active for the measured cell.
 
 Env/config knobs:
   REPRO_KERNELS = "pallas" | "interpret" | "ref"   (default: pallas on TPU,
-                                                    ref elsewhere)
+                                                    ref elsewhere;
+                                                    interpret is refused
+                                                    on TPU)
 """
 
 from __future__ import annotations
@@ -47,27 +49,28 @@ from . import ssd_scan as _ssd
 
 def backend() -> str:
     mode = os.environ.get("REPRO_KERNELS")
+    on_tpu = jax.default_backend() == "tpu"
+    if mode == "interpret" and on_tpu:
+        raise ValueError("REPRO_KERNELS=interpret on a TPU backend: the "
+                         "interpreter would stand in for the chip's kernels")
     if mode:
         return mode
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return "pallas" if on_tpu else "ref"
 
 
 # --------------------------------------------------------------------------
-# Availability probe + graceful fallback
+# Availability probe: CPU demotes to ref, TPU raises
 # --------------------------------------------------------------------------
 
-_PALLAS_OK: Optional[bool] = None
+_PROBED = False
+_PROBE_ERROR: Optional[Exception] = None
 
 
-def pallas_supported() -> bool:
-    """Can a Pallas kernel lower AND execute on this backend?  Cached.
-
-    Compiles and runs a minimal pallas_call (no interpret).  On backends
-    without Mosaic support (this CPU container) the lowering raises; we
-    catch everything because the failure mode is version/backend-specific.
-    """
-    global _PALLAS_OK
-    if _PALLAS_OK is None:
+def _probe_error() -> Optional[Exception]:
+    """Why a minimal Pallas kernel fails to lower and run here (cached);
+    None when it runs."""
+    global _PROBED, _PROBE_ERROR
+    if not _PROBED:
         try:
             from jax.experimental import pallas as pl
 
@@ -79,17 +82,31 @@ def pallas_supported() -> bool:
                 _probe, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
             )(x)
             jax.block_until_ready(out)
-            _PALLAS_OK = True
-        except Exception:
-            _PALLAS_OK = False
-    return _PALLAS_OK
+        except Exception as e:            # backend-specific lowering error
+            _PROBE_ERROR = e
+        _PROBED = True
+    return _PROBE_ERROR
+
+
+def pallas_supported() -> bool:
+    """Can a Pallas kernel lower AND execute on this backend?  Cached."""
+    return _probe_error() is None
 
 
 def resolve(op: str = "") -> str:
-    """Effective mode for one op call: ``backend()`` demoted to ``ref``
-    when Pallas is unavailable, with the demotion counted in obs."""
+    """Effective mode for one op call.
+
+    ``pallas`` demotes to ``ref`` when the probe fails on a backend
+    without Mosaic (the CPU), with the demotion counted in obs.  On a TPU
+    backend a failed probe raises: there the reference would hide the
+    chip's kernels.
+    """
     mode = backend()
     if mode == "pallas" and not pallas_supported():
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "Pallas kernels do not lower on this TPU backend"
+            ) from _probe_error()
         obs = obs_mod.get_active()
         if obs.enabled:
             obs.counter("kernels.fallback.pallas_unavailable").inc()

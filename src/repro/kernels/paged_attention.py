@@ -14,10 +14,14 @@ This is the indirection layer a continuous-batching engine needs: slots
 can grow page-by-page and the physical pages need not be contiguous; the
 kernel never sees anything but the table.
 
-Grid: ``(B, Hkv, n_pages)`` with pages innermost (sequential) so the
+Grid: ``(B, n_pages)`` with pages innermost (sequential) so the
 (m, l, acc) online-softmax state lives in VMEM scratch across a
-sequence's pages.  Query heads are grouped GQA-style: the ``g = Hq/Hkv``
-queries sharing a KV head ride along as rows of one block.
+sequence's pages.  One block holds a whole page, all KV heads, as a
+``(page, Hkv*hd)`` tile; the query enters block-diagonal over the KV
+heads (``(Hq, Hkv*hd)``, zero outside its own head's columns), so one
+score dot and one value dot serve every GQA group and the kernel never
+slices a head out of a tile.  The extra FLOPs (a factor Hkv) do not
+matter: decode attention is bound by the K/V bytes it streams.
 """
 
 from __future__ import annotations
@@ -32,10 +36,9 @@ import jax.experimental.pallas.tpu as pltpu
 
 
 def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, page: int, scale: float,
-                         n_pages: int):
+                         m_ref, l_ref, acc_ref, *, page: int, n_pages: int):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -43,28 +46,30 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale           # (g, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)                # (page, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    q = q_ref[0].astype(jnp.float32)                      # (Hq, Hkv*hd)
+    k = k_ref[0].astype(jnp.float32)                      # (page, Hkv*hd)
+    v = v_ref[0].astype(jnp.float32)
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)   # (g, page)
+    # q is block-diagonal over the KV heads, so row r scores only against
+    # its own head's K columns: one dot covers every GQA group.
+    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)   # (Hq, page)
     kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
     s = jnp.where(kpos < len_ref[b], s, -jnp.inf)
 
     # online softmax update (page 0 always holds position 0, so m starts
     # finite and fully-masked trailing pages contribute exact zeros)
-    m_prev = m_ref[...]                                   # (g, 1)
+    m_prev = m_ref[...]                                   # (Hq, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                                # (g, page)
+    p = jnp.exp(s - m_new)                                # (Hq, page)
     m_ref[...] = m_new
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
+        p, v, preferred_element_type=jnp.float32)         # (Hq, Hkv*hd)
 
     @pl.when(j == n_pages - 1)
     def _flush():
-        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -85,36 +90,45 @@ def paged_decode_attention(
     n_pages = block_table.shape[1]
     scale = scale if scale is not None else 1.0 / (hd ** 0.5)
 
-    q4 = q.reshape(B, Hkv, g, hd)
+    # A page block spans every KV head: (page, Hkv*hd) meets the TPU's
+    # (8, 128) block rule whatever Hkv and hd are (the last dim is whole).
+    # The merge of the two minor dims is a free reshape of the pool.
+    kf = k_pages.reshape(P, page, Hkv * hd)
+    vf = v_pages.reshape(P, page, Hkv * hd)
+    eye = jnp.eye(Hkv, dtype=q.dtype)
+    q_bd = jnp.einsum("bkgd,kj->bkgjd", q.reshape(B, Hkv, g, hd) * scale,
+                      eye).reshape(B, Hq, Hkv * hd)
+    width = Hkv * hd
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, n_pages),
+        grid=(B, n_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda b, h, j, tbl, lens:
-                         (b, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, hd), lambda b, h, j, tbl, lens:
-                         (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, hd), lambda b, h, j, tbl, lens:
-                         (tbl[b, j], 0, h, 0)),
+            pl.BlockSpec((1, Hq, width), lambda b, j, tbl, lens: (b, 0, 0)),
+            pl.BlockSpec((1, page, width),
+                         lambda b, j, tbl, lens: (tbl[b, j], 0, 0)),
+            pl.BlockSpec((1, page, width),
+                         lambda b, j, tbl, lens: (tbl[b, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b, h, j, tbl, lens:
-                               (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hq, width),
+                               lambda b, j, tbl, lens: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),      # running max
-            pltpu.VMEM((g, 1), jnp.float32),      # running denominator
-            pltpu.VMEM((g, hd), jnp.float32),     # output accumulator
+            pltpu.VMEM((Hq, 1), jnp.float32),      # running max
+            pltpu.VMEM((Hq, 1), jnp.float32),      # running denominator
+            pltpu.VMEM((Hq, width), jnp.float32),  # output accumulator
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, page=page, scale=scale,
-                          n_pages=n_pages),
+        functools.partial(_paged_decode_kernel, page=page, n_pages=n_pages),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, width), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="dmath_paged_decode",
     )(block_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q4, k_pages, v_pages)
-    return out.reshape(B, Hq, hd)
+      q_bd, kf, vf)
+    # row r of head h holds its output in column block h: keep the diagonal
+    h = jnp.arange(Hkv)
+    out = out.reshape(B, Hkv, g, Hkv, hd)[:, h, :, h]     # (Hkv, B, g, hd)
+    return out.transpose(1, 0, 2, 3).reshape(B, Hq, hd).astype(q.dtype)

@@ -13,11 +13,11 @@ PolyDL's measure-and-select discipline):
   the MXU busy and the hand kernel buys little, so dispatch keeps the
   reference path.
 
-Constants: HBM bandwidth matches ``benchmarks/roofline.py``'s per-chip
-number; effective FLOPs/s comes from :func:`repro.pipeline.costs.
-device_flops`, i.e. the *calibrated* value whenever a fitted
-CalibrationTable is active (the PR-7 loop) and the nominal otherwise —
-the gate sharpens automatically as the planner self-calibrates.
+Constants: HBM bandwidth is the device's entry in
+:data:`repro.core.chips.CHIPS`; effective FLOPs/s comes from
+:func:`repro.pipeline.costs.device_flops`, i.e. the *calibrated* value
+whenever a fitted CalibrationTable is active and the device's peak from
+the same table otherwise.  An unknown device raises.
 """
 
 from __future__ import annotations
@@ -25,15 +25,11 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict
 
-#: bytes/s of HBM per chip — same convention as benchmarks/roofline.py
-#: (TPU v5e-class).  Only the ratio against device_flops() matters.
-HBM_BYTES_PER_S = 819e9
-
-
 def ridge_intensity() -> float:
     """FLOPs/byte at which compute time equals memory time."""
+    from repro.core import chips
     from repro.pipeline import costs
-    return costs.device_flops() / HBM_BYTES_PER_S
+    return costs.device_flops() / chips.chip().hbm_bytes_per_s
 
 
 @dataclasses.dataclass(frozen=True)

@@ -33,20 +33,23 @@ def make_production_mesh(*, multi_pod: bool = False, pp: int = 1):
         shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-def make_mesh(shape, axes):
+def make_mesh(shape, axes, devices=None):
     """Arbitrary mesh for tests/benchmarks (same Auto axis types)."""
     return jax.make_mesh(
         tuple(shape), tuple(axes),
-        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices)
 
 
-def make_host_mesh(pp: int = 1):
-    """Whatever devices exist, as (data=n, model=1) — the layouts always
-    name both axes (smoke tests, examples).  ``pp > 1`` inserts a
-    ``pipe`` axis: (data=n/pp, pipe=pp, model=1)."""
-    n = len(jax.devices())
+def make_host_mesh(pp: int = 1, devices=None):
+    """``devices`` (default: every device) as (data=n, model=1) — the
+    layouts always name both axes (smoke tests, examples).  ``pp > 1``
+    inserts a ``pipe`` axis: (data=n/pp, pipe=pp, model=1)."""
+    devices = list(devices) if devices is not None else jax.devices()
+    n = len(devices)
     if pp > 1:
         if n % pp:
             raise ValueError(f"pp={pp} does not divide {n} devices")
-        return make_mesh((n // pp, pp, 1), ("data", "pipe", "model"))
-    return make_mesh((n, 1), ("data", "model"))
+        return make_mesh((n // pp, pp, 1), ("data", "pipe", "model"),
+                         devices)
+    return make_mesh((n, 1), ("data", "model"), devices)

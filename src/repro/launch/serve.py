@@ -24,6 +24,7 @@ import numpy as np
 
 from repro import obs as obs_mod
 from repro.api import Session
+from repro.launch import compile_cache
 from repro.serve import Request
 
 
@@ -51,6 +52,45 @@ def run(arch: str, *, n_requests: int = 8, batch_slots: int = 4,
         obs.close()
 
 
+def synthetic_requests(vocab: int, n: int, prompt_len, new_tokens: int,
+                       seed: int = 0):
+    """``n`` seeded random-token requests.  ``prompt_len`` is a length or
+    an inclusive ``(lo, hi)`` range drawn per request."""
+    rng = np.random.default_rng(seed)
+    lo, hi = (prompt_len, prompt_len) if isinstance(prompt_len, int) \
+        else prompt_len
+    reqs = []
+    for rid in range(n):
+        length = lo if lo == hi else int(rng.integers(lo, hi + 1))
+        reqs.append(Request(
+            rid=rid, prompt=rng.integers(0, vocab, length, dtype=np.int32),
+            max_new_tokens=new_tokens))
+    return reqs
+
+
+def drain(eng, n_submitted: int, max_ticks: int = 10_000):
+    """Tick ``eng`` until its queue and slots are empty.
+
+    Returns ``(tokens, tick_seconds)``: the decode tokens produced and
+    each tick's wall time (a tick ends on the host's read of the sampled
+    tokens, so it includes the device work).  Raises when a request was
+    refused or did not finish within ``max_ticks``.
+    """
+    total = 0
+    tick_s = []
+    while (eng.queue or any(r is not None for r in eng.active)) \
+            and len(tick_s) < max_ticks:
+        t0 = time.perf_counter()
+        total += eng.step()
+        tick_s.append(time.perf_counter() - t0)
+    refused = len(getattr(eng, "refused", ()))
+    if refused or len(eng.finished) != n_submitted:
+        raise RuntimeError(
+            f"{len(eng.finished)} of {n_submitted} requests finished "
+            f"({refused} refused) after {len(tick_s)} ticks")
+    return total, tick_s
+
+
 def _run(arch: str, obs, *, n_requests, batch_slots, max_seq, prompt_len,
          new_tokens, scale_down, seed, mesh, metrics, paged, page_size,
          scheduler, prefill_chunk, num_pages):
@@ -67,20 +107,12 @@ def _run(arch: str, obs, *, n_requests, batch_slots, max_seq, prompt_len,
                             scheduler=scheduler,
                             prefill_chunk=prefill_chunk,
                             num_pages=num_pages)
-        rng = np.random.default_rng(seed)
-        for rid in range(n_requests):
-            eng.submit(Request(
-                rid=rid,
-                prompt=rng.integers(0, cfg.vocab_size, prompt_len,
-                                    dtype=np.int32),
-                max_new_tokens=new_tokens))
+        for req in synthetic_requests(cfg.vocab_size, n_requests,
+                                      prompt_len, new_tokens, seed):
+            eng.submit(req)
         t0 = time.perf_counter()
-        total = 0
-        ticks = 0
-        while (eng.queue or any(r is not None for r in eng.active)) \
-                and ticks < 10_000:
-            total += eng.step()
-            ticks += 1
+        total, tick_s = drain(eng, n_requests)
+        ticks = len(tick_s)
         dt = time.perf_counter() - t0
     finished = len(eng.finished)
     print(f"{arch}: {n_requests} requests ({finished} finished), {total} "
@@ -136,6 +168,7 @@ def main():
                     help="write a JSONL telemetry stream (spans, prefill/"
                          "decode latency histograms) to PATH; default off")
     args = ap.parse_args()
+    compile_cache.enable()
     run(args.arch, n_requests=args.requests, batch_slots=args.batch_slots,
         max_seq=args.max_seq, new_tokens=args.new_tokens,
         scale_down=args.scale_down, metrics=args.metrics,

@@ -26,6 +26,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs.base import scale_config  # noqa: F401  (legacy import site)
 from repro.core import memory as mem_mod
 from repro.data import Pipeline, Stage, SyntheticLM
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_mod
 from repro.obs import report as report_mod
 from repro.train import AdamWConfig, ResilientStepLoop, StepTimeWatchdog, \
@@ -371,6 +372,7 @@ def main():
                          "inline JSON list of FaultSpec dicts, e.g. "
                          '\'[{"seam": "train.nonfinite", "step": 3}]\'')
     args = ap.parse_args()
+    compile_cache.enable()
     try:
         losses = run(args.arch, steps=args.steps, batch=args.batch,
                      seq=args.seq, scale_down=args.scale_down, lr=args.lr,

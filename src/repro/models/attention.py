@@ -24,7 +24,7 @@ from typing import Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from repro.core import precision
+from repro.core import manual, precision
 from repro.core.layout import Layout, constrain
 from repro.core.planner import ParallelPlan
 from repro.models import layers
@@ -181,8 +181,8 @@ def _tp_attention_shardmap(x, p, cfg, plan, mesh, *, policy, window,
         return y, k, v
 
     kv_spec = P(plan.batch_axes, None, tp, None)
-    y, k, v = jax.shard_map(
-        body, check_vma=False, mesh=mesh,
+    y, k, v = manual.shard_map(
+        body, mesh=mesh,
         in_specs=(P(plan.batch_axes, tp, None),
                   {k_: head_specs[k_] for k_ in p}),
         out_specs=(P(plan.batch_axes, tp, None), kv_spec, kv_spec),
@@ -222,8 +222,8 @@ def _sp_attention(x, p, cfg, plan, mesh, *, policy, window, q_chunk,
         return out, k, v
 
     out_spec = plan.seq_act().spec
-    out, k, v = jax.shard_map(
-        body, check_vma=False, mesh=mesh,
+    out, k, v = manual.shard_map(
+        body, mesh=mesh,
         in_specs=(x_spec, p_specs),
         out_specs=(out_spec, out_spec, out_spec),
     )(x, {k_: p[k_] for k_ in p})

@@ -12,7 +12,7 @@ from typing import Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from repro.core import precision
+from repro.core import manual, precision
 from repro.core.layout import Layout, constrain
 
 NEG = -1e30
@@ -275,8 +275,8 @@ def glu_mlp_shardmap(x, w_gate, w_in, w_out, *, act, mesh, plan, policy):
         return jax.lax.psum_scatter(out.astype(xl.dtype), tp,
                                     scatter_dimension=1, tiled=True)
 
-    return jax.shard_map(
-        body, check_vma=False, mesh=mesh,
+    return manual.shard_map(
+        body, mesh=mesh,
         in_specs=(P(plan.batch_axes, tp, None), P(None, tp), P(None, tp),
                   P(tp, None)),
         out_specs=P(plan.batch_axes, tp, None),
@@ -316,8 +316,8 @@ def embed_shard_map(tokens: jax.Array, table: jax.Array, mesh, *,
         e = jnp.take(tab, tok, axis=0)
         return e * mult if mult is not None else e
 
-    return jax.shard_map(
-        body, check_vma=False, mesh=mesh,
+    return manual.shard_map(
+        body, mesh=mesh,
         in_specs=(P(batch_axes, None), P(None, tp_axis)),
         out_specs=P(batch_axes, None, tp_axis),
     )(tokens, table)

@@ -30,7 +30,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import precision
+from repro.core import manual, precision
 from repro.core.layout import Layout
 from repro.core.planner import ParallelPlan
 from repro.models import layers
@@ -155,8 +155,8 @@ def forward(
         aux = jax.lax.pmean(aux, plan.batch_axes)
         return y, aux
 
-    y, aux = jax.shard_map(
-        body, check_vma=False, mesh=mesh,
+    y, aux = manual.shard_map(
+        body, mesh=mesh,
         in_specs=(x_spec, rep2, exp_spec, exp_spec, exp_spec),
         out_specs=(out_spec, jax.sharding.PartitionSpec()),
     )(x, p["router"], p["w_gate"], p["w_in"], p["w_out"])

@@ -15,7 +15,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import precision
+from repro.core import manual, precision
 from repro.core.layout import Layout, constrain
 from repro.core.planner import ParallelPlan
 from repro.models import layers
@@ -218,8 +218,8 @@ def forward_shardmap(
         return out, conv_new, state, bc_new
 
     ba = plan.batch_axes
-    out, conv_new, state, bc_new = jax.shard_map(
-        body, check_vma=False, mesh=mesh,
+    out, conv_new, state, bc_new = manual.shard_map(
+        body, mesh=mesh,
         in_specs=(P(ba, tp, None), {k: specs[k] for k in p}),
         out_specs=(P(ba, tp, None), P(ba, None, tp),
                    P(ba, tp, None, None), P(ba, None, None)),

@@ -21,20 +21,15 @@ from __future__ import annotations
 
 from typing import Optional
 
-#: nominal per-device peak used to turn FLOPs into seconds.  Only the
-#: *relative* magnitude against the alpha-beta comms terms matters for
-#: candidate ranking (same convention as the LinkSpec defaults).
-DEVICE_FLOPS = 100e12
-
-
 def device_flops() -> float:
     """Effective per-device FLOPs/s: the fitted value from the active
     calibration table when one is installed
-    (:func:`repro.core.calibrate.set_active`), else the hand-set
-    :data:`DEVICE_FLOPS` nominal."""
-    from repro.core import calibrate
+    (:func:`repro.core.calibrate.set_active`), else the peak of the
+    device JAX reports, from :data:`repro.core.chips.CHIPS` (an unknown
+    device raises)."""
+    from repro.core import calibrate, chips
     fitted = calibrate.device_flops()
-    return fitted if fitted else DEVICE_FLOPS
+    return fitted if fitted else chips.chip().peak_flops
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:

@@ -66,42 +66,29 @@ _outfiles: Dict[str, str] = {}
 #: Persistent XLA compilation cache, keyed PER TEST CELL (the ROADMAP
 #: tier-1 wall-time lever): each child suite / dry-run cell gets its own
 #: directory under the base so concurrent children never contend on the
-#: same entries, and a re-run (locally or via the CI cache restore) loads
-#: yesterday's executables instead of recompiling them.
+#: same entries, and a re-run loads the executables the last one wrote.
+#: A caller's JAX_COMPILATION_CACHE_DIR wins over the per-cell path (every
+#: process then shares the caller's directory).
 #:
-#: REPRO_XLA_CACHE_DIR=<dir> forces the cache ON at <dir>; =off disables
-#: it; unset -> auto.  Auto DISABLES the cache on the CPU backend below
-#: jaxlib 0.5: deserialized XLA:CPU executables are broken there
-#: (jaxlib 0.4.36 segfaults/heap-corrupts on the first cache hit of a
-#: donated train step).  Re-tested 2026-08 on the pinned jaxlib 0.4.36:
-#: minimal repros (two identical jits, even a donated shard_map train
-#: step) now pass, but the real Session train step still segfaults
-#: deterministically — REPRO_XLA_CACHE_DIR=<dir> on the
-#: test_api_session.py child crashes inside the deserialized executable
-#: on both the populate and the hit run.  The gate stands; the wiring
-#: lights up unchanged on real accelerators or a newer pin.
+#: REPRO_XLA_CACHE_DIR=<dir> moves the per-cell base; =off disables it.
 _XLA_CACHE_BASE = os.environ.get(
     "REPRO_XLA_CACHE_DIR",
     os.path.join(_TESTS_DIR, "..", ".cache", "xla"))
 
 
-def _cache_supported() -> bool:
-    if _XLA_CACHE_BASE == "off":
-        return False
-    if os.environ.get("REPRO_XLA_CACHE_DIR"):
-        return True                       # explicit opt-in wins
-    try:
-        import jax
-        import jaxlib
-        ver = tuple(int(x) for x in jaxlib.__version__.split(".")[:2])
-        return jax.default_backend() != "cpu" or ver >= (0, 5)
-    except Exception:
-        return False
+def _caller_cache_dir() -> Optional[str]:
+    """The caller's JAX_COMPILATION_CACHE_DIR; a per-cell directory this
+    module handed to a parent process (and its xdist workers) is not."""
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    base = os.path.abspath(_XLA_CACHE_BASE)
+    if d and os.path.commonpath([os.path.abspath(d), base]) != base:
+        return d
+    return None
 
 
 def compile_cache_env(cell: str) -> Dict[str, str]:
     """Env vars enabling the per-cell persistent compilation cache."""
-    if not _cache_supported():
+    if _XLA_CACHE_BASE == "off" or _caller_cache_dir():
         return {}
     d = os.path.join(os.path.abspath(_XLA_CACHE_BASE), cell)
     try:

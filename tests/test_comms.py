@@ -38,7 +38,6 @@ else:
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    import repro  # noqa: F401  (installs jax compat shims)
     from repro.comms import (CommsPlan, flatten_buckets, plan_buckets,
                              sync_tree, topology_from_mesh,
                              unflatten_buckets, wire_all_reduce)

@@ -74,7 +74,6 @@ def test_collective_wire_formulas():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
         sys.path.insert(0, %r)
-        import repro                     # installs jax compat shims
         from benchmarks.hlo_cost import analyze_text
 
         mesh = jax.make_mesh((8,), ("m",),

@@ -38,8 +38,10 @@ if not _in_child():
         # --hbm-gib override wins over everything
         b = mem.budget_for(platform="v5e", hbm_gib=32)
         assert b.hbm_bytes == 32 * mem.GIB
-        # unknown platform falls back to the default
-        assert mem.budget_for(platform="nope").platform == "v5e"
+        # no device named: the v5e default; an unknown one raises
+        assert mem.budget_for().platform == "v5e"
+        with pytest.raises(ValueError, match="nope"):
+            mem.budget_for(platform="nope")
 
     def test_headroom_single_source_of_truth():
         """The ISSUE bug: two call sites applied different headroom

@@ -307,7 +307,6 @@ def test_sync_tree_records_per_step_wire_bytes():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    import repro  # noqa: F401  (installs jax compat shims)
     from repro.comms import CommsPlan, sync_tree
     from repro.launch.mesh import make_mesh
 
@@ -344,7 +343,6 @@ def test_session_obs_streams_spans_and_keeps_losses_bit_identical(tmp_path):
     import jax.numpy as jnp
     import numpy as np
 
-    import repro  # noqa: F401
     from repro.api import Session
     from repro.launch.mesh import make_mesh
     from repro.train import AdamWConfig

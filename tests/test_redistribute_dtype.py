@@ -31,7 +31,6 @@ else:
     import jax.numpy as jnp
     import numpy as np
 
-    import repro  # noqa: F401  (installs jax compat shims)
     from repro.core.layout import Layout
     from repro.core.redistribute import relayout, relayout_explicit
     from repro.launch.mesh import make_mesh
