@@ -124,14 +124,10 @@ _DECISIONS: Dict[str, Dict] = {}
 
 
 def _record(d: "_roofline.GateDecision", mode: str) -> bool:
-    """Log a gate decision (latest per op wins) and bump obs counters.
-    Returns whether the fused kernel actually runs (gate AND backend)."""
+    """Log a gate decision (latest per op wins).  Returns whether the
+    fused kernel actually runs (gate AND backend)."""
     active = d.fused and mode in ("pallas", "interpret")
     _DECISIONS[d.op] = {**d.to_dict(), "mode": mode, "active": active}
-    obs = obs_mod.get_active()
-    if obs.enabled:
-        verdict = "fused" if active else "ref"
-        obs.counter(f"kernels.dispatch.{d.op}.{verdict}").inc()
     return active
 
 

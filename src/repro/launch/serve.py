@@ -34,8 +34,9 @@ def run(arch: str, *, n_requests: int = 8, batch_slots: int = 4,
         metrics: Optional[str] = None, paged: bool = False,
         page_size: int = 64, scheduler: str = "static",
         prefill_chunk: int = 32, num_pages: Optional[int] = None):
-    # --metrics: stream plan/lower spans + per-request prefill/decode
-    # latency histograms as JSONL; off -> NULL obs, output unchanged.
+    # --metrics: stream plan/lower/serve-phase spans + per-request TTFT
+    # and queue-wait histograms as JSONL; off -> NULL obs, output
+    # unchanged (the spans still annotate any profiler trace).
     obs = obs_mod.Obs(jsonl=metrics, name=f"serve/{arch}") if metrics \
         else obs_mod.NULL
     prev_obs = obs_mod.set_active(obs)
@@ -119,7 +120,7 @@ def _run(arch: str, obs, *, n_requests, batch_slots, max_seq, prompt_len,
           f"tokens in {dt:.2f}s ({total / dt:.1f} tok/s, {ticks} ticks)")
     if obs.enabled:
         session.publish_metrics()
-        for name in ("serve.prefill_s", "serve.decode_s", "serve.ttft_s",
+        for name in ("span.serve.tick.s", "serve.ttft_s",
                      "serve.queue_wait_s"):
             s = obs.histogram(name).summary()
             if s.get("count"):
@@ -165,8 +166,8 @@ def main():
                     help="continuous pool pages incl. the NULL page "
                          "(default: full static capacity, budget-clamped)")
     ap.add_argument("--metrics", type=str, default=None, metavar="PATH",
-                    help="write a JSONL telemetry stream (spans, prefill/"
-                         "decode latency histograms) to PATH; default off")
+                    help="write a JSONL telemetry stream (spans, tick/TTFT/"
+                         "queue-wait histograms) to PATH; default off")
     args = ap.parse_args()
     compile_cache.enable()
     run(args.arch, n_requests=args.requests, batch_slots=args.batch_slots,

@@ -10,6 +10,7 @@ scattered ``print`` lines:
 - :mod:`repro.obs.trace` — nestable :class:`Span` context managers (host
   phases time directly; device work registers outputs via
   ``Span.block`` so the span closes over ``jax.block_until_ready``),
+  each also a ``repro.<name>`` annotation in any ``jax.profiler`` trace,
 - :mod:`repro.obs.sink` — the JSONL event stream + atomic
   ``BENCH_*.json`` snapshot writer (the on-disk perf trajectory),
 - :mod:`repro.obs.report` — the predicted-vs-measured drift report the
@@ -18,9 +19,11 @@ scattered ``print`` lines:
 The :class:`Obs` facade bundles one registry + tracer + sink;
 :data:`NULL` is the disabled singleton every instrumented call site
 defaults to, so with metrics off the hot paths see cheap no-ops and
-numerics/test output are unchanged.  Code that runs far from a
-:class:`~repro.api.Session` handle (e.g. ``comms.sync_tree`` at trace
-time) reads the process-wide active instance via :func:`get_active`.
+numerics/test output are unchanged.  Its spans keep only their profiler
+annotation, so a profiler trace shows the program's phases either way.
+Code that runs far from a :class:`~repro.api.Session` handle (e.g.
+``comms.sync_tree`` at trace time) reads the process-wide active
+instance via :func:`get_active`.
 """
 
 from __future__ import annotations
@@ -31,12 +34,12 @@ from typing import Dict, Optional, Sequence
 from .metrics import (Counter, Gauge, Histogram,  # noqa: F401 (re-export)
                       MetricRegistry)
 from .sink import JsonlSink, NullSink, read_jsonl, write_snapshot
-from .trace import NULL_SPAN, Span, Tracer
+from .trace import AnnotationSpan, Span, Tracer
 
 __all__ = [
     "Obs", "NULL", "get_active", "set_active",
     "MetricRegistry", "Counter", "Gauge", "Histogram",
-    "Tracer", "Span", "NULL_SPAN",
+    "Tracer", "Span", "AnnotationSpan",
     "JsonlSink", "NullSink", "read_jsonl", "write_snapshot",
 ]
 
@@ -118,16 +121,17 @@ _NULL_METRIC = _NullMetric()
 
 
 class _NullObs(Obs):
-    """Metrics-off: every verb is a no-op (guard hot-path extras — timing
-    syscalls, ``block_until_ready`` — behind ``obs.enabled``)."""
+    """Metrics-off: every verb is a no-op but :meth:`span`, which keeps the
+    span's profiler annotation (guard hot-path extras — timing syscalls —
+    behind ``obs.enabled``)."""
 
     enabled = False
 
     def __init__(self):
         super().__init__(jsonl=None, name="null")
 
-    def span(self, name: str, **attrs):
-        return NULL_SPAN
+    def span(self, name: str, **attrs) -> AnnotationSpan:
+        return AnnotationSpan(name)
 
     def counter(self, name: str):
         return _NULL_METRIC

@@ -8,10 +8,20 @@ makes naive host timing meaningless; register the step's outputs with
 :meth:`Span.block` and the span closes over ``jax.block_until_ready`` so
 the recorded duration covers real execution, not just dispatch.
 
+Every span holds a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>`` open while it runs, so it lands on the profiler's own
+clock beside the device's ops whenever a ``jax.profiler`` trace is being
+taken (and costs about a microsecond when none is).  The annotation
+carries the name only: attributes would be encoded into the event name
+and split one phase into many labels.
+
 Every closed span (a) appends a ``{"kind": "span", ...}`` event to the
 tracer's sink and (b) observes its duration into the ``span.<name>.s``
 histogram of the tracer's metric registry — so the same measurement feeds
 both the raw trace and the p50/p99 summaries the drift report consumes.
+:class:`AnnotationSpan` is the annotation alone, what a disabled
+:class:`~repro.obs.Obs` hands out: no clock read, no event, no histogram,
+no sync.
 """
 
 from __future__ import annotations
@@ -21,12 +31,17 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
+#: prefix of every span's name in a profiler trace
+PREFIX = "repro."
+
 
 class Span:
     """One timed phase; use via ``with tracer.span("step") as sp:``."""
 
     __slots__ = ("name", "attrs", "id", "parent", "t_wall", "seconds",
-                 "_tracer", "_t0", "_sync")
+                 "_tracer", "_t0", "_sync", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self.name = name
@@ -38,6 +53,7 @@ class Span:
         self._tracer = tracer
         self._t0: float = 0.0
         self._sync: List[Any] = []
+        self._ann: Optional[TraceAnnotation] = None
 
     def block(self, value):
         """Register device output(s) to ``block_until_ready`` at close.
@@ -49,6 +65,9 @@ class Span:
         return value
 
     def __enter__(self) -> "Span":
+        # the annotation starts when it is made, so it is made here
+        self._ann = TraceAnnotation(PREFIX + self.name)
+        self._ann.__enter__()
         self.id = self._tracer._next_id()
         stack = self._tracer._stack()
         self.parent = stack[-1].id if stack else None
@@ -58,38 +77,44 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        if self._sync:
-            import jax
-            jax.block_until_ready(self._sync)
-            self._sync.clear()
-        self.seconds = time.perf_counter() - self._t0
-        stack = self._tracer._stack()
-        if stack and stack[-1] is self:
-            stack.pop()
-        self._tracer._emit(self, error=exc_type.__name__ if exc_type
-                           else None)
+        try:
+            if self._sync:
+                import jax
+                jax.block_until_ready(self._sync)
+                self._sync.clear()
+            self.seconds = time.perf_counter() - self._t0
+            stack = self._tracer._stack()
+            if stack and stack[-1] is self:
+                stack.pop()
+            self._tracer._emit(self, error=exc_type.__name__ if exc_type
+                               else None)
+        finally:
+            self._ann.__exit__(exc_type, exc, tb)
 
 
-class _NullSpan:
-    """No-op stand-in returned by disabled tracers/obs."""
+class AnnotationSpan:
+    """The profiler annotation of a span and nothing else: what a
+    disabled obs returns, so program spans reach any ``jax.profiler``
+    trace with telemetry off.  :meth:`block` does not sync."""
 
-    __slots__ = ()
-    name = "null"
+    __slots__ = ("name", "_ann")
     id = None
     parent = None
     seconds = 0.0
 
+    def __init__(self, name: str):
+        self.name = name
+
     def block(self, value):
         return value
 
-    def __enter__(self) -> "_NullSpan":
+    def __enter__(self) -> "AnnotationSpan":
+        self._ann = TraceAnnotation(PREFIX + self.name)
+        self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        return None
-
-
-NULL_SPAN = _NullSpan()
+        self._ann.__exit__(exc_type, exc, tb)
 
 
 class Tracer:

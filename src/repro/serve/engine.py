@@ -83,7 +83,6 @@ def _retire(engine, b: int) -> Request:
     engine.finished.append(req)
     engine.active[b] = None
     engine.pos[b] = 0
-    engine.obs.counter("serve.retired").inc()
     return req
 
 
@@ -95,9 +94,6 @@ class Engine:
                  opcache=None, registry=None, cache_key: str = None,
                  obs=None, paged: bool = False, page_size: int = 64,
                  prefill_chunk: int = 32):
-        # prefill/decode latency histograms + token counters; the NULL
-        # default keeps the tick loop free of timing syscalls and
-        # block_until_ready sync points when telemetry is off.
         self.obs = obs if obs is not None else obs_mod.NULL
         self.model = model
         self.params = params
@@ -207,7 +203,6 @@ class Engine:
             if self.active[b] is None and self.queue:
                 req = self.queue.pop(0)
                 req.admit_t = time.perf_counter()
-                t0 = time.perf_counter() if self.obs.enabled else 0.0
                 if self.paged:
                     # slot-major page ownership: slot b's table row is
                     # constant, prefill streams the prompt through the
@@ -220,11 +215,6 @@ class Engine:
                     last_logits, self.cache = self._prefill_one(
                         self.params, self.cache, toks,
                         jnp.asarray(b, jnp.int32))
-                if self.obs.enabled:
-                    jax.block_until_ready(last_logits)
-                    self.obs.histogram("serve.prefill_s").observe(
-                        time.perf_counter() - t0)
-                    self.obs.counter("serve.prefills").inc()
                 nxt = self._sample(last_logits)[0]
                 req.out.append(int(nxt))
                 req.first_token_t = time.perf_counter()
@@ -257,13 +247,8 @@ class Engine:
         # park at 0; their garbage write is overwritten by the next
         # prefill before anything attends it)
         pos = jnp.asarray(self.pos)
-        t0 = time.perf_counter() if self.obs.enabled else 0.0
         logits, self.cache = self._decode(
             self.params, self.cache, jnp.asarray(tokens), pos)
-        if self.obs.enabled:
-            jax.block_until_ready(logits)
-            self.obs.histogram("serve.decode_s").observe(
-                time.perf_counter() - t0)
         self._publish_cache()
         nxt = self._sample(logits[:, 0, :])
         n_active = 0
@@ -275,7 +260,6 @@ class Engine:
             n_active += 1
             if len(r.out) >= r.max_new_tokens or self.pos[b] >= self.T - 1:
                 _retire(self, b)
-        self.obs.counter("serve.decode_tokens").inc(n_active)
         return n_active
 
     def run(self, max_ticks: int = 10_000) -> List[Request]:
@@ -390,20 +374,24 @@ class ContinuousEngine:
         return list(self.sched.shed)
 
     def submit(self, req: Request):
-        refusal = self.sched.submit(req)
-        if refusal is not None and self.obs.enabled:
-            self.obs.counter("serve.refusals").inc()
+        self.sched.submit(req)
 
     def _publish_cache(self):
         if self._registry is not None and self._cache_key is not None:
             self._registry.replace_value(self._cache_key, self.cache)
 
-    def _sample(self, logits):
+    def _sample(self, logits) -> np.ndarray:
+        """The token each row samples, read back to the host; callers
+        hold a ``serve.sample`` span around the slice of ``logits`` and
+        this."""
         if self.temperature == 0.0:
-            return np.asarray(jnp.argmax(logits, -1))
-        self.key, k = jax.random.split(self.key)
-        return np.asarray(jax.random.categorical(
-            k, logits / self.temperature, axis=-1))
+            ids = jnp.argmax(logits, -1)
+        else:
+            self.key, k = jax.random.split(self.key)
+            ids = jax.random.categorical(
+                k, logits / self.temperature, axis=-1)
+        with self.obs.span("serve.readback"):
+            return np.asarray(ids)
 
     def _release_slot(self, req: Request, b: int):
         self.blocks.free(req.rid)
@@ -442,26 +430,22 @@ class ContinuousEngine:
             P = len(req.prompt)
             start = req.prefill_pos
             n = min(C, P - start)
-            chunk = np.zeros((1, C), np.int32)
-            chunk[0, :n] = req.prompt[start:start + n]
-            row = jnp.asarray(self.blocks.table_row(req.rid))
-            t0 = time.perf_counter() if self.obs.enabled else 0.0
-            logits, self.cache = self._prefill_chunk_fn(
-                self.params, self.cache, jnp.asarray(chunk), row,
-                jnp.asarray(start, jnp.int32))
-            if self.obs.enabled:
-                jax.block_until_ready(logits)
-                self.obs.histogram("serve.prefill_s").observe(
-                    time.perf_counter() - t0)
+            with self.obs.span("serve.prefill"):
+                chunk = np.zeros((1, C), np.int32)
+                chunk[0, :n] = req.prompt[start:start + n]
+                row = jnp.asarray(self.blocks.table_row(req.rid))
+                logits, self.cache = self._prefill_chunk_fn(
+                    self.params, self.cache, jnp.asarray(chunk), row,
+                    jnp.asarray(start, jnp.int32))
             req.prefill_pos = start + n
             if req.prefill_pos >= P:      # final chunk: first token
-                nxt = self._sample(logits[:, n - 1, :])[0]
+                with self.obs.span("serve.sample"):
+                    nxt = self._sample(logits[:, n - 1, :])[0]
                 req.out.append(int(nxt))
                 req.first_token_t = time.perf_counter()
                 if self.obs.enabled:
                     self.obs.histogram("serve.ttft_s").observe(
                         req.first_token_t - req.submit_t)
-                    self.obs.counter("serve.prefills").inc()
                 self.pos[b] = P
                 self._table_np[b] = self.blocks.table_row(req.rid)
                 self._table_dirty = True
@@ -507,35 +491,44 @@ class ContinuousEngine:
     # ------------------------------------------------------------------
     def step(self) -> int:
         """One engine tick: admit, prefill one chunk each, extend/preempt,
-        decode one token for every ready slot, retire finished."""
+        decode one token for every ready slot, retire finished.
+
+        Each phase runs under a span (``serve.tick`` around the whole
+        step; inside it ``serve.admit``, one ``serve.prefill`` per chunk,
+        ``serve.extend``, ``serve.decode``, and ``serve.sample`` holding
+        ``serve.readback``), which a profiler trace shows as
+        ``repro.serve.*`` with telemetry on or off."""
+        with self.obs.span("serve.tick"):
+            return self._step()
+
+    def _step(self) -> int:
         for hook in self.tick_hooks:
             hook(self._tick)
         self._tick += 1
-        self._admit()
+        with self.obs.span("serve.admit"):
+            self._admit()
         self._prefill_tick()
         ready = [b for b, r in enumerate(self.active)
                  if r is not None and r.prefill_pos >= len(r.prompt)]
-        ready = self._extend_or_preempt(ready)
+        with self.obs.span("serve.extend"):
+            ready = self._extend_or_preempt(ready)
         n_ready = len(ready)
         if n_ready:
-            if self._table_dirty:
-                self.cache = dict(self.cache,
-                                  table=jnp.asarray(self._table_np))
-                self._table_dirty = False
-            tokens = np.zeros((self.B, 1), np.int32)
-            pos = np.zeros(self.B, np.int32)
-            for b in ready:
-                tokens[b, 0] = self.active[b].out[-1]
-                pos[b] = self.pos[b]
-            t0 = time.perf_counter() if self.obs.enabled else 0.0
-            logits, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(pos))
-            if self.obs.enabled:
-                jax.block_until_ready(logits)
-                self.obs.histogram("serve.decode_s").observe(
-                    time.perf_counter() - t0)
-            nxt = self._sample(logits[:, 0, :])
+            with self.obs.span("serve.decode"):
+                if self._table_dirty:
+                    self.cache = dict(self.cache,
+                                      table=jnp.asarray(self._table_np))
+                    self._table_dirty = False
+                tokens = np.zeros((self.B, 1), np.int32)
+                pos = np.zeros(self.B, np.int32)
+                for b in ready:
+                    tokens[b, 0] = self.active[b].out[-1]
+                    pos[b] = self.pos[b]
+                logits, self.cache = self._decode(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(pos))
+            with self.obs.span("serve.sample"):
+                nxt = self._sample(logits[:, 0, :])
             for b in ready:
                 r = self.active[b]
                 r.out.append(int(nxt[b]))
@@ -543,7 +536,6 @@ class ContinuousEngine:
                 if len(r.out) >= r.max_new_tokens \
                         or self.pos[b] >= self.T - 1:
                     _retire(self, b)
-            self.obs.counter("serve.decode_tokens").inc(n_ready)
         self._publish_cache()
         if self.obs.enabled:
             self.obs.gauge("serve.pool_blocks_used").set(

@@ -395,3 +395,160 @@ def test_session_obs_streams_spans_and_keeps_losses_bit_identical(tmp_path):
     assert obs.histogram("span.step.s").count == step_spans.count("step")
     # opcache/state gauges were published on the instrumented path
     assert obs.gauge("state.resident_bytes").value > 0
+
+
+# --------------------------------------------------------------------------
+# spans on the profiler clock; the serve loop's phase spans
+# --------------------------------------------------------------------------
+
+class _Annotations:
+    """Stand-in for ``jax.profiler.TraceAnnotation`` recording
+    (name, enter/exit) in order."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class Ann:
+            def __enter__(self):
+                log.append((name, "enter"))
+
+            def __exit__(self, *exc):
+                log.append((name, "exit"))
+        return Ann()
+
+
+def test_spans_hold_a_profiler_annotation_named_without_attrs(monkeypatch):
+    from repro.obs import trace as trace_mod
+    anns = _Annotations()
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", anns)
+    obs = obs_mod.Obs()
+    with obs.span("outer", phase="plan"):
+        with obs_mod.NULL.span("inner", x=1):
+            pass
+    assert anns.log == [("repro.outer", "enter"), ("repro.inner", "enter"),
+                        ("repro.inner", "exit"), ("repro.outer", "exit")]
+    with pytest.raises(ValueError):
+        with obs.span("boom"):
+            raise ValueError("x")
+    assert anns.log[-1] == ("repro.boom", "exit")
+
+
+def test_null_span_is_annotation_only(monkeypatch):
+    import jax
+
+    def no_sync(*a, **k):
+        raise AssertionError("a NULL span synced")
+
+    monkeypatch.setattr(jax, "block_until_ready", no_sync)
+    sp = obs_mod.NULL.span("serve.tick", slot=3)
+    assert isinstance(sp, obs_mod.AnnotationSpan)
+    with sp:
+        assert sp.block(7) == 7
+    assert sp.seconds == 0.0 and sp.id is None and sp.parent is None
+    assert obs_mod.NULL.metrics.summary() == \
+        obs_mod.MetricRegistry().summary()
+
+
+SERVE_TINY = dict(name="obs-serve-tiny", family="dense", n_layers=2,
+                  d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
+                  d_ff=128, vocab_size=64)
+
+
+@pytest.fixture(scope="module")
+def tiny_serve():
+    """run(obs) -> (outputs by rid, ticks) of three ragged requests
+    through a two-slot ContinuousEngine; the engines share one compiled
+    step set."""
+    import jax
+    import numpy as np
+
+    from repro.configs.base import ModelConfig
+    from repro.core.opcache import OpCache
+    from repro.core.planner import plan_for
+    from repro.launch.mesh import make_mesh
+    from repro.models import Model
+    from repro.serve import ContinuousEngine, Request
+
+    cfg = ModelConfig(**SERVE_TINY)
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(mesh):
+        model = Model(cfg, mesh, plan_for(cfg, mesh), q_chunk=16,
+                      kv_chunk=16)
+        params = jax.device_put(model.init(jax.random.PRNGKey(3)),
+                                model.param_shardings())
+    cache = OpCache("test-obs")
+
+    def run(obs):
+        with jax.set_mesh(mesh):
+            eng = ContinuousEngine(model, params, batch_slots=2,
+                                   max_seq=32, page_size=8, prefill_chunk=4,
+                                   opcache=cache, obs=obs)
+            rng = np.random.default_rng(0)
+            for r in range(3):
+                eng.submit(Request(
+                    rid=r, prompt=rng.integers(0, 64, 3 + 2 * r,
+                                               dtype=np.int32),
+                    max_new_tokens=4))
+            ticks = 0
+            while eng.queue or any(r is not None for r in eng.active):
+                eng.step()
+                ticks += 1
+        return {r.rid: list(r.out) for r in eng.finished}, ticks
+
+    run(None)                                   # compile
+    return run
+
+
+def test_serve_obs_on_makes_the_same_tokens_without_syncing(
+        tiny_serve, monkeypatch):
+    import jax
+
+    off, ticks = tiny_serve(None)
+
+    def no_sync(*a, **k):
+        raise AssertionError("telemetry synced the serve loop")
+
+    monkeypatch.setattr(jax, "block_until_ready", no_sync)
+    obs = obs_mod.Obs()
+    on, ticks_on = tiny_serve(obs)
+    assert on == off and ticks_on == ticks and len(on) == 3
+    h = obs.metrics.summary()["histograms"]
+    assert h["span.serve.tick.s"]["count"] == ticks
+    assert h["span.serve.readback.s"]["count"] == \
+        h["span.serve.sample.s"]["count"]
+    assert h["serve.ttft_s"]["count"] == 3
+    assert "serve.prefill_s" not in h and "serve.decode_s" not in h
+
+
+def test_profiler_capture_holds_one_tick_span_per_tick(tiny_serve,
+                                                       tmp_path):
+    """Telemetry off: the phases still reach a CPU profiler trace."""
+    import jax
+
+    from bench import program_trace
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _, ticks = tiny_serve(None)
+    finally:
+        jax.profiler.stop_trace()
+    p = program_trace.reduce(str(tmp_path))
+    n_tick, tick_s, _ = p.program["repro.serve.tick"]
+    n_sample, sample_s, _ = p.program["repro.serve.sample"]
+    n_read, read_s, _ = p.program["repro.serve.readback"]
+    assert n_tick == ticks and n_read == n_sample
+    assert read_s <= sample_s <= tick_s
+    for phase in ("admit", "extend"):
+        assert p.count("repro.serve." + phase) == ticks
+    # three prompts of 3, 5 and 7 tokens in chunks of 4
+    assert p.count("repro.serve.prefill") == 1 + 2 + 2
+    # every span sits inside a tick: the ticks' self time is what is left
+    inner = sum(t for n, (_, _, t) in p.program.items()
+                if n != "repro.serve.tick")
+    assert inner + p.program["repro.serve.tick"][2] == \
+        pytest.approx(tick_s, rel=1e-6)
