@@ -73,16 +73,17 @@ def fused_table():
     B, Hq, Hkv, hd, page, nb = 4, 8, 4, 64, 64, 8
     P, T = B * nb, nb * page
     q = jax.random.normal(jax.random.PRNGKey(1), (B, Hq, hd), jnp.float32)
-    kp = jax.random.normal(jax.random.PRNGKey(2), (P, page, Hkv, hd),
+    kp = jax.random.normal(jax.random.PRNGKey(2), (1, P, page, Hkv * hd),
                            jnp.float32)
-    vp = jax.random.normal(jax.random.PRNGKey(3), (P, page, Hkv, hd),
+    vp = jax.random.normal(jax.random.PRNGKey(3), (1, P, page, Hkv * hd),
                            jnp.float32)
     tbl = jnp.asarray(np.random.default_rng(0).permutation(P)
                       .reshape(B, nb).astype(np.int32))
     lens = jnp.full((B,), T - 7, jnp.int32)
-    got = kpaged.paged_decode_attention(q, kp, vp, tbl, lens,
+    lyr = jnp.asarray(0, jnp.int32)
+    got = kpaged.paged_decode_attention(q, kp, vp, tbl, lens, lyr,
                                         interpret=True)
-    want = jax.jit(ref.paged_decode_attention)(q, kp, vp, tbl, lens)
+    want = jax.jit(ref.paged_decode_attention)(q, kp, vp, tbl, lens, lyr)
     kv_bytes = 2 * B * T * Hkv * hd * 4
     q_bytes = q.size * 4
     gate = roofline.gate("paged_decode_attention",
@@ -92,8 +93,9 @@ def fused_table():
                          bytes_fused=kv_bytes + 2 * q_bytes)
     rows.append(_row(
         "paged_decode_attention", [B, Hq, hd, page, nb],
-        lambda: jax.jit(ref.paged_decode_attention)(q, kp, vp, tbl, lens),
-        lambda: kpaged.paged_decode_attention(q, kp, vp, tbl, lens,
+        lambda: jax.jit(ref.paged_decode_attention)(q, kp, vp, tbl, lens,
+                                                    lyr),
+        lambda: kpaged.paged_decode_attention(q, kp, vp, tbl, lens, lyr,
                                               interpret=True),
         got, want, gate))
 
@@ -122,7 +124,7 @@ def fused_table():
     # this host's actual routing (gate verdict x backend demotion)
     from repro.kernels import ops
     ops.quantize_compress(x[:4096])
-    ops.paged_decode_attention(q, kp, vp, tbl, lens)
+    ops.paged_decode_attention(q, kp, vp, tbl, lens, lyr)
     ops.matmul_dequant(a, bq, bs, out_dtype=jnp.float32)
     doc = {"meta": {"backend": jax.default_backend(),
                     "dispatch": ops.dispatch_report(),

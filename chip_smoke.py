@@ -193,17 +193,20 @@ def kernel_phase(cfg, seed: int) -> None:
     Hq, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(kq, (SLOTS, Hq, hd), jnp.bfloat16)
-    k_pages = jax.random.normal(kk, (n_pages, page, Hkv, hd), jnp.bfloat16)
-    v_pages = jax.random.normal(kv, (n_pages, page, Hkv, hd), jnp.bfloat16)
+    # a two-layer stacked pool, read at layer 1
+    pool = (2, n_pages, page, Hkv * hd)
+    k_pages = jax.random.normal(kk, pool, jnp.bfloat16)
+    v_pages = jax.random.normal(kv, pool, jnp.bfloat16)
     rng = np.random.default_rng(seed)
     table = jnp.asarray(1 + rng.permutation(n_pages - 1).reshape(
         SLOTS, n_row), jnp.int32)
     lens = jnp.asarray(rng.integers(1, MAX_SEQ + 1, SLOTS), jnp.int32)
+    layer = jnp.asarray(1, jnp.int32)
 
     got = jax.jit(kops.paged_decode_attention)(q, k_pages, v_pages, table,
-                                                lens)
+                                                lens, layer)
     want = jax.jit(ref.paged_decode_attention)(q, k_pages, v_pages, table,
-                                               lens)
+                                               lens, layer)
     got = np.asarray(got.astype(jnp.float32))
     want = np.asarray(want.astype(jnp.float32))
     decision = kops.dispatch_report()["ops"]["paged_decode_attention"]

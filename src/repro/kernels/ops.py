@@ -215,10 +215,13 @@ quantize_int8_per_channel = _ref.quantize_int8_per_channel  # offline prep
 # Paged-attention decode (serving engine)
 # --------------------------------------------------------------------------
 
-def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens, *,
-                           scale=None):
+def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens,
+                           layer, *, scale=None):
+    """Decode attention of one layer of the stacked ``(L, P, page,
+    Hkv*hd)`` pool (see :mod:`repro.kernels.paged_attention`)."""
     B, Hq, hd = q.shape
-    P, page, Hkv, _ = k_pages.shape
+    page = k_pages.shape[2]
+    Hkv = k_pages.shape[3] // hd
     n_pages = block_table.shape[1]
     mode = resolve("paged_decode_attention")
     T = n_pages * page
@@ -233,10 +236,10 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens, *,
                        bytes_fused=kv_bytes + 2 * q_bytes)
     if _record(d, mode):
         return _paged.paged_decode_attention(
-            q, k_pages, v_pages, block_table, seq_lens, scale=scale,
+            q, k_pages, v_pages, block_table, seq_lens, layer, scale=scale,
             interpret=(mode == "interpret"))
     return _ref.paged_decode_attention(q, k_pages, v_pages, block_table,
-                                       seq_lens, scale=scale)
+                                       seq_lens, layer, scale=scale)
 
 
 # --------------------------------------------------------------------------

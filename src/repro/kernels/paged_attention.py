@@ -2,13 +2,15 @@
 
 The engine's dense decode attends one query token per sequence against a
 ``(B, S_max, Hkv, hd)`` cache, touching ``S_max`` rows no matter how short
-the live sequence is.  Here the KV cache lives in fixed-size *pages*
-``(P, page, Hkv, hd)`` and each sequence owns an ordered list of page
-indices (its row of ``block_table``).  The kernel walks a sequence's pages
-through a scalar-prefetched indices table — the grid index map reads
-``block_table[b, j]`` to pick which physical page to stream next — and
-runs the classic online-softmax accumulation across pages, masking the
-tail of the last live page against ``seq_lens``.
+the live sequence is.  Here the KV cache lives in fixed-size *pages*,
+stacked over the layers as ``(L, P, page, Hkv*hd)`` with the KV heads
+merged into the minor dim, and each sequence owns an ordered list of
+page indices (its row of ``block_table``).  The kernel walks a
+sequence's pages through scalar-prefetched operands — the grid index map
+reads the layer and ``block_table[b, j]`` to pick which physical page to
+stream next — and runs the classic online-softmax accumulation across
+pages, masking the tail of the last live page against ``seq_lens``.
+The pool is read where it lies: no layer is sliced out or relaid.
 
 This is the indirection layer a continuous-batching engine needs: slots
 can grow page-by-page and the physical pages need not be contiguous; the
@@ -16,12 +18,13 @@ kernel never sees anything but the table.
 
 Grid: ``(B, n_pages)`` with pages innermost (sequential) so the
 (m, l, acc) online-softmax state lives in VMEM scratch across a
-sequence's pages.  One block holds a whole page, all KV heads, as a
-``(page, Hkv*hd)`` tile; the query enters block-diagonal over the KV
-heads (``(Hq, Hkv*hd)``, zero outside its own head's columns), so one
-score dot and one value dot serve every GQA group and the kernel never
-slices a head out of a tile.  The extra FLOPs (a factor Hkv) do not
-matter: decode attention is bound by the K/V bytes it streams.
+sequence's pages.  One block holds a whole page of one layer, all KV
+heads, as a ``(page, Hkv*hd)`` tile; the query enters block-diagonal
+over the KV heads (``(Hq, Hkv*hd)``, zero outside its own head's
+columns), so one score dot and one value dot serve every GQA group and
+the kernel never slices a head out of a tile.  The extra FLOPs (a factor
+Hkv) do not matter: decode attention is bound by the K/V bytes it
+streams.
 """
 
 from __future__ import annotations
@@ -35,8 +38,9 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 
-def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, page: int, n_pages: int):
+def _paged_decode_kernel(tbl_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
+                         o_ref, m_ref, l_ref, acc_ref, *, page: int,
+                         n_pages: int):
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -75,42 +79,44 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention(
     q: jax.Array,             # (B, Hq, hd)   one query token per sequence
-    k_pages: jax.Array,       # (P, page, Hkv, hd)
-    v_pages: jax.Array,       # (P, page, Hkv, hd)
+    k_pages: jax.Array,       # (L, P, page, Hkv*hd) the stacked pool
+    v_pages: jax.Array,       # (L, P, page, Hkv*hd)
     block_table: jax.Array,   # (B, n_pages) int32 — physical page per slot
     seq_lens: jax.Array,      # (B,) int32 — live length (pos + 1)
+    layer: jax.Array,         # int32 scalar — the layer of the pool to read
     *,
     scale: Optional[float] = None,
     interpret: bool = False,
 ) -> jax.Array:
     B, Hq, hd = q.shape
-    P, page, Hkv, hd2 = k_pages.shape
-    assert hd == hd2 and Hq % Hkv == 0, (q.shape, k_pages.shape)
+    page, width = k_pages.shape[2:]
+    assert width % hd == 0, (q.shape, k_pages.shape)
+    Hkv = width // hd
+    assert Hq % Hkv == 0, (q.shape, k_pages.shape)
     g = Hq // Hkv
     n_pages = block_table.shape[1]
     scale = scale if scale is not None else 1.0 / (hd ** 0.5)
 
-    # A page block spans every KV head: (page, Hkv*hd) meets the TPU's
-    # (8, 128) block rule whatever Hkv and hd are (the last dim is whole).
-    # The merge of the two minor dims is a free reshape of the pool.
-    kf = k_pages.reshape(P, page, Hkv * hd)
-    vf = v_pages.reshape(P, page, Hkv * hd)
+    # A page block spans every KV head of one layer: (page, Hkv*hd) meets
+    # the TPU's (8, 128) block rule whatever Hkv and hd are (the last two
+    # dims are whole), and the layer dim is squeezed out of the block.
     eye = jnp.eye(Hkv, dtype=q.dtype)
     q_bd = jnp.einsum("bkgd,kj->bkgjd", q.reshape(B, Hkv, g, hd) * scale,
-                      eye).reshape(B, Hq, Hkv * hd)
-    width = Hkv * hd
+                      eye).reshape(B, Hq, width)
+    kv_spec = pl.BlockSpec(
+        (None, 1, page, width),
+        lambda b, j, tbl, lens, lyr: (lyr[0], tbl[b, j], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, n_pages),
         in_specs=[
-            pl.BlockSpec((1, Hq, width), lambda b, j, tbl, lens: (b, 0, 0)),
-            pl.BlockSpec((1, page, width),
-                         lambda b, j, tbl, lens: (tbl[b, j], 0, 0)),
-            pl.BlockSpec((1, page, width),
-                         lambda b, j, tbl, lens: (tbl[b, j], 0, 0)),
+            pl.BlockSpec((1, Hq, width),
+                         lambda b, j, tbl, lens, lyr: (b, 0, 0)),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=pl.BlockSpec((1, Hq, width),
-                               lambda b, j, tbl, lens: (b, 0, 0)),
+                               lambda b, j, tbl, lens, lyr: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hq, 1), jnp.float32),      # running max
             pltpu.VMEM((Hq, 1), jnp.float32),      # running denominator
@@ -127,7 +133,7 @@ def paged_decode_attention(
         interpret=interpret,
         name="dmath_paged_decode",
     )(block_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q_bd, kf, vf)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), q_bd, k_pages, v_pages)
     # row r of head h holds its output in column block h: keep the diagonal
     h = jnp.arange(Hkv)
     out = out.reshape(B, Hkv, g, Hkv, hd)[:, h, :, h]     # (Hkv, B, g, hd)

@@ -116,28 +116,32 @@ def attention(
 
 def paged_decode_attention(
     q: jax.Array,             # (B, Hq, hd)   one query token per sequence
-    k_pages: jax.Array,       # (P, page, Hkv, hd)
-    v_pages: jax.Array,       # (P, page, Hkv, hd)
+    k_pages: jax.Array,       # (L, P, page, Hkv*hd) the stacked pool
+    v_pages: jax.Array,       # (L, P, page, Hkv*hd)
     block_table: jax.Array,   # (B, n_pages) int32
     seq_lens: jax.Array,      # (B,) int32 — live length (pos + 1)
+    layer: jax.Array,         # int32 scalar — the layer of the pool to read
     *,
     scale: Optional[float] = None,
 ) -> jax.Array:
     """Gather-then-attend definition of the paged decode kernel.
 
-    Logical page j of sequence b is physical page ``block_table[b, j]``;
-    gathering rebuilds the dense (B, T, Hkv, hd) cache, then the math is
+    Logical page j of sequence b is physical page ``block_table[b, j]`` of
+    layer ``layer``; gathering rebuilds the dense (B, T, Hkv, hd) cache
+    from those rows alone, then the math is
     ``models/layers.decode_attention`` with the mask ``t < seq_lens[b]``.
     """
     B, Hq, hd = q.shape
-    _, page, Hkv, _ = k_pages.shape
+    page, width = k_pages.shape[2:]
+    Hkv = width // hd
     n_pages = block_table.shape[1]
     g = Hq // Hkv
     T = n_pages * page
     scale = scale if scale is not None else 1.0 / (hd ** 0.5)
 
-    kf = k_pages[block_table].reshape(B, T, Hkv, hd).astype(jnp.float32)
-    vf = v_pages[block_table].reshape(B, T, Hkv, hd).astype(jnp.float32)
+    kf = k_pages[layer, block_table].reshape(B, T, Hkv, hd)
+    vf = v_pages[layer, block_table].reshape(B, T, Hkv, hd)
+    kf, vf = kf.astype(jnp.float32), vf.astype(jnp.float32)
     qf = q.astype(jnp.float32).reshape(B, Hkv, g, hd) * scale
 
     s = jnp.einsum("bkgd,btkd->bkgt", qf, kf)            # (B,Hkv,g,T)

@@ -273,40 +273,45 @@ def decode_paged(
     p: dict,
     cfg,
     plan: ParallelPlan,
-    k_pages: jax.Array,            # (P, page, Hkv, hd) physical page pool
+    k_pages: jax.Array,            # (L, P, page, Hkv*hd) stacked page pool
     v_pages: jax.Array,
+    layer: jax.Array,              # int32 scalar: this layer's index
     block_table: jax.Array,        # (B, n_pages) int32 logical -> physical
     pos: jax.Array,                # scalar or (B,) position of the new token
     *,
     policy,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One decode step against a block-paged KV cache.
+    """One decode step of layer ``layer`` against the stacked paged pool.
 
-    The new token scatters into physical page ``block_table[b, pos//page]``
-    at offset ``pos % page``; attention then walks the sequence's pages
-    through :func:`repro.kernels.ops.paged_decode_attention` (the Pallas
-    kernel where it lowers, the gather-based oracle elsewhere).  ``pos``
-    may be scalar (lockstep static-batch decode) or per-slot ``(B,)``
-    (continuous batching); every position ``<= pos[b]`` is live, so
-    ``seq_lens`` is simply ``pos + 1`` per slot.
+    The new token scatters into row ``[layer, block_table[b, pos//page],
+    pos % page]`` of the pool; attention then walks the sequence's pages
+    of that layer through :func:`repro.kernels.ops.paged_decode_attention`
+    (the Pallas kernel where it lowers, the gather-based oracle
+    elsewhere).  The pool is touched only where it changes: B rows
+    written, the live pages read.  ``pos`` may be scalar (lockstep
+    static-batch decode) or per-slot ``(B,)`` (continuous batching);
+    every position ``<= pos[b]`` is live, so ``seq_lens`` is simply
+    ``pos + 1`` per slot.
     """
     from repro.kernels import ops as kops
 
     B = x.shape[0]
-    page = k_pages.shape[1]
+    page, width = k_pages.shape[2:]
     positions = pos[None] if pos.ndim == 0 else pos[:, None]
     q, k, v = _qkv(x, p, cfg, plan, positions, policy)         # (B,1,H,hd)
 
     pos_b = jnp.broadcast_to(pos, (B,))
     phys = block_table[jnp.arange(B), pos_b // page]           # (B,)
     off = pos_b % page
-    k_pages = k_pages.at[phys, off].set(k[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[phys, off].set(v[:, 0].astype(v_pages.dtype))
+    k_pages = k_pages.at[layer, phys, off].set(
+        k[:, 0].reshape(B, width).astype(k_pages.dtype))
+    v_pages = v_pages.at[layer, phys, off].set(
+        v[:, 0].reshape(B, width).astype(v_pages.dtype))
 
     seq_lens = (pos_b + 1).astype(jnp.int32)
     out = kops.paged_decode_attention(
         q[:, 0].astype(k_pages.dtype), k_pages, v_pages,
-        block_table, seq_lens)                                 # (B,H,hd)
+        block_table, seq_lens, layer)                          # (B,H,hd)
     y = precision.einsum("bshk,hkd->bsd", out[:, None].astype(q.dtype),
                          p["wo"], policy=policy)
     return y.astype(x.dtype), k_pages, v_pages
@@ -317,8 +322,9 @@ def prefill_chunk_paged(
     p: dict,
     cfg,
     plan: ParallelPlan,
-    k_pages: jax.Array,            # (P, page, Hkv, hd) physical page pool
+    k_pages: jax.Array,            # (L, P, page, Hkv*hd) stacked page pool
     v_pages: jax.Array,
+    layer: jax.Array,              # int32 scalar: this layer's index
     table_row: jax.Array,          # (n_pages,) int32 logical -> physical
     start: jax.Array,              # scalar: absolute position of chunk[0]
     *,
@@ -326,12 +332,15 @@ def prefill_chunk_paged(
     q_chunk: int = 512,
     kv_chunk: int = 1024,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One fixed-size prefill chunk for ONE sequence against the paged pool.
+    """One fixed-size prefill chunk of layer ``layer`` for ONE sequence
+    against the stacked paged pool.
 
-    Scatters the chunk's K/V into the sequence's pages (through the block
-    table, so the allocator may hand out pages in any order), gathers the
-    row back in LOGICAL page order, and runs the flash body with
-    ``q_offset=start``.  Correctness of the padding/garbage regions:
+    Scatters the chunk's K/V into the sequence's pages of that layer
+    (through the block table, so the allocator may hand out pages in any
+    order), gathers the sequence's row back in LOGICAL page order, and
+    runs the flash body with ``q_offset=start``.  Only the chunk's rows
+    are written and only the sequence's row is read.  Correctness of the
+    padding/garbage regions:
 
     - end-padding positions ``>= start + n_real`` are beyond every real
       query's causal horizon, so their scores are masked (their K/V lands
@@ -343,19 +352,21 @@ def prefill_chunk_paged(
       continuous free-list allocator produce bit-identical outputs.
     """
     C = x.shape[1]
-    page = k_pages.shape[1]
+    page, width = k_pages.shape[2:]
     positions = start + jnp.arange(C)
     q, k, v = _qkv(x, p, cfg, plan, positions, policy)         # (1,C,H,hd)
 
     page_idx = positions // page
     phys = table_row[page_idx]                                 # (C,)
     off = positions % page
-    k_pages = k_pages.at[phys, off].set(k[0].astype(k_pages.dtype))
-    v_pages = v_pages.at[phys, off].set(v[0].astype(v_pages.dtype))
+    k_pages = k_pages.at[layer, phys, off].set(
+        k[0].reshape(C, width).astype(k_pages.dtype))
+    v_pages = v_pages.at[layer, phys, off].set(
+        v[0].reshape(C, width).astype(v_pages.dtype))
 
-    n_pages = table_row.shape[0]
-    k_row = k_pages[table_row].reshape(1, n_pages * page, *k_pages.shape[2:])
-    v_row = v_pages[table_row].reshape(1, n_pages * page, *v_pages.shape[2:])
+    row = (1, table_row.shape[0] * page) + k.shape[2:]
+    k_row = k_pages[layer, table_row].reshape(row)             # (1,T,Hkv,hd)
+    v_row = v_pages[layer, table_row].reshape(row)
     out = layers.flash_attention_jnp(
         q.transpose(0, 2, 1, 3), k_row.transpose(0, 2, 1, 3),
         v_row.transpose(0, 2, 1, 3),

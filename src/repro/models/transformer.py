@@ -429,17 +429,9 @@ class Model:
         batching allocator can later hand out pages in any order without
         touching the kernel.
         """
-        cfg = self.cfg
-        assert self.paged_supported(), (
-            f"paged decode unsupported for family={cfg.family!r} "
-            f"window={cfg.window} softcap={cfg.attn_softcap}")
         nb = -(-seq_len // page_size)
-        shape = (cfg.n_layers, batch * nb, page_size, cfg.n_kv_heads,
-                 cfg.d_head)
         table = jnp.arange(batch * nb, dtype=jnp.int32).reshape(batch, nb)
-        return {"k_pages": jnp.zeros(shape, jnp.bfloat16),
-                "v_pages": jnp.zeros(shape, jnp.bfloat16),
-                "table": table}
+        return dict(self.init_paged_pool(batch * nb, page_size), table=table)
 
     def init_paged_pool(self, num_pages: int,
                         page_size: int = 64) -> Dict[str, jax.Array]:
@@ -456,8 +448,11 @@ class Model:
         assert self.paged_supported(), (
             f"paged decode unsupported for family={cfg.family!r} "
             f"window={cfg.window} softcap={cfg.attn_softcap}")
-        shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads,
-                 cfg.d_head)
+        # (L, P, page, Hkv*hd): the heads merged into the minor dim, the
+        # block the decode kernel streams, so every layer reads and writes
+        # the pool where it lies
+        shape = (cfg.n_layers, num_pages, page_size,
+                 cfg.n_kv_heads * cfg.d_head)
         return {"k_pages": jnp.zeros(shape, jnp.bfloat16),
                 "v_pages": jnp.zeros(shape, jnp.bfloat16)}
 
@@ -479,10 +474,9 @@ class Model:
         def body(carry, xs):
             x, kp, vp = carry
             lp, i = xs
-            kc, vc = kp[i], vp[i]
             h = layers.rms_norm(x, lp["ln1"], cfg.norm_eps)
-            a, kc, vc = attention.prefill_chunk_paged(
-                h, lp["attn"], cfg, plan, kc, vc, table_row, start,
+            a, kp, vp = attention.prefill_chunk_paged(
+                h, lp["attn"], cfg, plan, kp, vp, i, table_row, start,
                 policy=self.policy, q_chunk=self.q_chunk,
                 kv_chunk=self.kv_chunk)
             x = x + a
@@ -494,8 +488,6 @@ class Model:
                 f = layers.glu_mlp(
                     h, lp["mlp"]["gate"], lp["mlp"]["in"],
                     lp["mlp"]["out"], act=cfg.act, policy=self.policy)
-            kp = jax.lax.dynamic_update_index_in_dim(kp, kc, i, 0)
-            vp = jax.lax.dynamic_update_index_in_dim(vp, vc, i, 0)
             return (x + f, kp, vp), None
 
         (x, k_new, v_new), _ = jax.lax.scan(
@@ -516,10 +508,9 @@ class Model:
         def body(carry, xs):
             x, kp, vp = carry
             lp, i = xs
-            kc, vc = kp[i], vp[i]
             h = layers.rms_norm(x, lp["ln1"], cfg.norm_eps)
-            a, kc, vc = attention.decode_paged(
-                h, lp["attn"], cfg, plan, kc, vc, table, pos,
+            a, kp, vp = attention.decode_paged(
+                h, lp["attn"], cfg, plan, kp, vp, i, table, pos,
                 policy=self.policy)
             x = x + a
             h = layers.rms_norm(x, lp["ln2"], cfg.norm_eps)
@@ -530,8 +521,6 @@ class Model:
                 f = layers.glu_mlp(
                     h, lp["mlp"]["gate"], lp["mlp"]["in"],
                     lp["mlp"]["out"], act=cfg.act, policy=self.policy)
-            kp = jax.lax.dynamic_update_index_in_dim(kp, kc, i, 0)
-            vp = jax.lax.dynamic_update_index_in_dim(vp, vc, i, 0)
             return (x + f, kp, vp), None
 
         (x, k_new, v_new), _ = jax.lax.scan(

@@ -129,37 +129,49 @@ else:
     # ------------------------------------------------------------------
     # paged-attention decode
     # ------------------------------------------------------------------
+    #: layers of the stacked test pool
+    N_LAYERS = 3
+
     def _paged_case(seed, B, Hq, Hkv, hd, page, nb, dtype, permute=True):
+        """A stacked ``(L, P, page, Hkv*hd)`` pool, each layer its own
+        random draw, with a (permuted) block table and ragged lengths."""
         rng = np.random.default_rng(seed)
         P = B * nb
         q = _rand((B, Hq, hd), seed=seed, dtype=dtype)
-        kp = _rand((P, page, Hkv, hd), seed=seed + 1, dtype=dtype)
-        vp = _rand((P, page, Hkv, hd), seed=seed + 2, dtype=dtype)
+        kp = _rand((N_LAYERS, P, page, Hkv * hd), seed=seed + 1, dtype=dtype)
+        vp = _rand((N_LAYERS, P, page, Hkv * hd), seed=seed + 2, dtype=dtype)
         phys = rng.permutation(P) if permute else np.arange(P)
         tbl = jnp.asarray(phys.reshape(B, nb).astype(np.int32))
         lens = jnp.asarray(
             rng.integers(1, nb * page + 1, size=B).astype(np.int32))
         return q, kp, vp, tbl, lens
 
+    @pytest.mark.parametrize("layer", [0, N_LAYERS - 1])
     @pytest.mark.parametrize("gqa", [(8, 4), (4, 4), (6, 2)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_paged_decode_matches_reference(gqa, dtype):
+    def test_paged_decode_matches_reference(gqa, dtype, layer):
         # permuted block tables prove the kernel really reads through the
-        # indices table; ragged seq_lens exercise the per-page mask tails
+        # indices table; ragged seq_lens exercise the per-page mask tails;
+        # a layer other than 0 proves it reads the layer it is given
         Hq, Hkv = gqa
         q, kp, vp, tbl, lens = _paged_case(11, 3, Hq, Hkv, 64, 16, 4,
                                            dtype)
+        lyr = jnp.asarray(layer, jnp.int32)
         got = paged_attention.paged_decode_attention(q, kp, vp, tbl, lens,
-                                                     interpret=True)
-        want = ref.paged_decode_attention(q, kp, vp, tbl, lens)
+                                                     lyr, interpret=True)
+        want = ref.paged_decode_attention(q, kp, vp, tbl, lens, lyr)
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want, np.float32),
             **TOL[dtype])
+        other = ref.paged_decode_attention(q, kp, vp, tbl, lens, 1)
+        assert not np.allclose(np.asarray(got, np.float32),
+                               np.asarray(other, np.float32), **TOL[dtype])
 
-    def test_paged_oracle_matches_dense_decode_attention():
+    @pytest.mark.parametrize("layer", [0, N_LAYERS - 1])
+    def test_paged_oracle_matches_dense_decode_attention(layer):
         """The paged oracle with an identity table equals the production
-        dense-cache decode attention (models/layers.decode_attention) —
-        the semantics the serving engine swaps out."""
+        dense-cache decode attention (models/layers.decode_attention) of
+        the same layer — the semantics the serving engine swaps out."""
         from repro.models import layers
         B, Hq, Hkv, hd, page, nb = 2, 8, 4, 32, 8, 3
         q, kp, vp, tbl, lens = _paged_case(7, B, Hq, Hkv, hd, page, nb,
@@ -167,12 +179,13 @@ else:
         pos = int(lens.max()) - 1
         lens = jnp.full((B,), pos + 1, jnp.int32)      # lockstep decode
         T = nb * page
-        k_dense = np.asarray(kp).reshape(B, T, Hkv, hd)
-        v_dense = np.asarray(vp).reshape(B, T, Hkv, hd)
+        k_dense = np.asarray(kp)[layer].reshape(B, T, Hkv, hd)
+        v_dense = np.asarray(vp)[layer].reshape(B, T, Hkv, hd)
         want = layers.decode_attention(
             q[:, :, None, :], jnp.asarray(k_dense), jnp.asarray(v_dense),
             jnp.asarray(pos, jnp.int32))[:, :, 0, :]
-        got = ref.paged_decode_attention(q, kp, vp, tbl, lens)
+        got = ref.paged_decode_attention(q, kp, vp, tbl, lens,
+                                         jnp.asarray(layer, jnp.int32))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 

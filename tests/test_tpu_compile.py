@@ -15,13 +15,17 @@ import this file.  Keep these tests in this one file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import fused, paged_attention
+from repro.configs.base import ModelConfig
+from repro.kernels import fused, ops, paged_attention
+from repro.launch.mesh import make_host_mesh
+from repro.models import Model
 
 #: qwen2-0.5b decode widths: 14 query heads over 2 KV heads (g=7), hd 64
 HQ, HKV, HD, PAGE = 14, 2, 64, 64
@@ -58,14 +62,100 @@ def _assert_kernel(compiled):
 @pytest.mark.parametrize("batch,n_row", [(8, 32), (1, 1)])
 def test_paged_decode_compiles_for_v5e(one_chip, batch, n_row):
     n_pages = 1 + batch * n_row
+    pool = (2, n_pages, PAGE, HKV * HD)
     f = jax.jit(paged_attention.paged_decode_attention)
     compiled = f.lower(
         _sds((batch, HQ, HD), jnp.bfloat16, one_chip),
-        _sds((n_pages, PAGE, HKV, HD), jnp.bfloat16, one_chip),
-        _sds((n_pages, PAGE, HKV, HD), jnp.bfloat16, one_chip),
+        _sds(pool, jnp.bfloat16, one_chip),
+        _sds(pool, jnp.bfloat16, one_chip),
         _sds((batch, n_row), jnp.int32, one_chip),
-        _sds((batch,), jnp.int32, one_chip)).compile()
+        _sds((batch,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip)).compile()
     _assert_kernel(compiled)
+
+
+# ---------------------------------------------------------------------------
+# The paged model steps touch the pool only where it changes
+# ---------------------------------------------------------------------------
+
+#: pages of the guard's pool: a few hundred, a count no other dim has
+N_POOL = 301
+N_SLOTS, N_ROW, CHUNK = 8, 32, 32
+
+#: ``%name = bf16[d0,d1,...]{layout} opcode(`` of one HLO instruction
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def _paged_model(one_chip, monkeypatch):
+    """A 2-layer dense model at qwen2-0.5b's head widths on the described
+    chip, with the Pallas paged kernel selected as on a TPU backend."""
+    monkeypatch.setenv("REPRO_KERNELS", "pallas")
+    monkeypatch.setattr(ops, "_PROBED", True)
+    monkeypatch.setattr(ops, "_PROBE_ERROR", None)
+    cfg = ModelConfig(name="paged-guard", family="dense", n_layers=2,
+                      d_model=896, n_heads=HQ, n_kv_heads=HKV, head_dim=HD,
+                      d_ff=1024, vocab_size=1024, qkv_bias=True)
+    mesh = make_host_mesh(devices=list(one_chip.device_set))
+    model = Model(cfg, mesh)
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    with jax.set_mesh(mesh):
+        params = shapes(jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0))))
+        pool = shapes(jax.eval_shape(
+            lambda: model.init_paged_pool(N_POOL, PAGE)))
+    return model, mesh, params, pool
+
+
+def _layer_pool_copies(hlo: str):
+    """Instructions whose result is one whole layer of the pool, in the
+    merged ``(P, page, Hkv*hd)`` or the split ``(P, page, Hkv, hd)``
+    layout (with or without a unit layer dim), other than parameters and
+    bitcasts, and copies of the whole stacked pool."""
+    layer = {(N_POOL, PAGE, HKV * HD), (N_POOL, PAGE, HKV, HD)}
+    layer |= {(1,) + s for s in layer}
+    stacked = {(2,) + s for s in layer if s[0] == N_POOL}
+    bad = []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if not m or not m.group(1):
+            continue
+        dims, op = tuple(int(d) for d in m.group(1).split(",")), m.group(2)
+        if dims in layer and op not in ("parameter", "bitcast"):
+            bad.append(line.strip()[:160])
+        if dims in stacked and op in ("copy", "copy-start", "transpose"):
+            bad.append(line.strip()[:160])
+    return bad
+
+
+def test_decode_step_paged_reads_the_pool_in_place(one_chip, monkeypatch):
+    """No layer of the pool is sliced out, relaid for the kernel or
+    written back: each layer's token scatters into the stacked pool and
+    the kernel reads that pool where it lies."""
+    model, mesh, params, pool = _paged_model(one_chip, monkeypatch)
+    cache = dict(pool, table=_sds((N_SLOTS, N_ROW), jnp.int32, one_chip))
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(model.decode_step_paged, donate_argnums=(1,)).lower(
+            params, cache, _sds((N_SLOTS, 1), jnp.int32, one_chip),
+            _sds((N_SLOTS,), jnp.int32, one_chip)).compile()
+    _assert_kernel(compiled)
+    assert _layer_pool_copies(compiled.as_text()) == []
+
+
+def test_prefill_chunk_paged_reads_the_pool_in_place(one_chip, monkeypatch):
+    """The chunk scatters into the stacked pool and only the sequence's
+    row is gathered back: no whole layer is sliced out or written back."""
+    model, mesh, params, pool = _paged_model(one_chip, monkeypatch)
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(model.prefill_chunk_paged,
+                           donate_argnums=(1,)).lower(
+            params, pool, _sds((1, CHUNK), jnp.int32, one_chip),
+            _sds((N_ROW,), jnp.int32, one_chip),
+            _sds((), jnp.int32, one_chip)).compile()
+    assert _layer_pool_copies(compiled.as_text()) == []
 
 
 #: a 32 MiB fp32 gradient bucket
