@@ -17,7 +17,10 @@ One chip runs three phases through the user's entry points:
   max_seq 2048 answers 16 seeded requests (prompts of 128-1024 tokens,
   64 new tokens each).  Every request must finish, none refused.
 - kernel: the paged decode as dispatched (the Pallas kernel on TPU)
-  against ``kernels.ref.paged_decode_attention`` at the model's widths.
+  against ``kernels.ref.paged_decode_attention`` at the model's widths,
+  and the latent (MLA) paged decode against
+  ``kernels.ref.paged_decode_latent_attention`` at DeepSeek-V2-Lite's
+  (16 heads over a 512 + 64 latent, 32 slots of 8192 positions).
 
 ``--chips 4`` runs only the data-parallel train path on the host's four
 chips (mesh data=4, model=1, explicit comms gradient sync) and compares
@@ -35,6 +38,7 @@ this one process: a chip belongs to one process at a time.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import statistics
@@ -65,6 +69,10 @@ DP_RTOL = 2e-3
 #: paged decode, bf16 inputs, fp32 softmax in both paths, bf16 output: a
 #: couple of bf16 ulps (2^-7 at magnitude 1) of summation-order slack.
 KERNEL_ATOL = KERNEL_RTOL = 2e-2
+#: latent decode: the kernel feeds bfloat16 query and probabilities to the
+#: matmuls (the oracle keeps float32): 2% of the output's largest value,
+#: as the CPU test in interpret mode (tests/test_deepseek_v2.py)
+LATENT_TOL = 2e-2
 
 
 class SmokeFailure(Exception):
@@ -219,6 +227,47 @@ def kernel_phase(cfg, seed: int) -> None:
           f"paged decode matches the reference (atol=rtol={KERNEL_ATOL})")
 
 
+def latent_kernel_phase(seed: int) -> None:
+    """The latent decode kernel at DeepSeek-V2-Lite's widths, read at
+    layer 1 of a two-layer pool with pages out of order and ragged
+    lengths, against the gather oracle."""
+    from repro.configs import get_config
+    from repro.kernels import ops as kops
+    from repro.kernels import paged_attention, ref
+    from repro.models import mla
+
+    cfg = get_config("deepseek-v2-lite")
+    slots, max_seq, page = 32, 8192, 256
+    r, dr, H = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.n_heads
+    pack = paged_attention.rope_pack(dr)
+    n_row = max_seq // page
+    n_pages = 1 + slots * n_row
+    kq, kc, kp = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(kq, (slots, H, r + dr), jnp.float32)
+    c = jax.random.normal(kc, (2, n_pages, page, r), jnp.bfloat16)
+    pe = jax.random.normal(kp, (2, n_pages, page // pack, pack * dr),
+                           jnp.bfloat16)
+    rng = np.random.default_rng(seed)
+    table = jnp.asarray(1 + rng.permutation(n_pages - 1).reshape(
+        slots, n_row), jnp.int32)
+    lens = jnp.asarray(rng.integers(1, max_seq + 1, slots), jnp.int32)
+    args = (q, c, pe, table, lens, jnp.asarray(1, jnp.int32))
+    scale = mla.softmax_scale(cfg)
+    got = np.asarray(jax.jit(functools.partial(
+        kops.paged_decode_latent_attention, scale=scale))(*args))
+    want = np.asarray(jax.jit(functools.partial(
+        ref.paged_decode_latent_attention, scale=scale))(*args))
+    err = float(np.max(np.abs(got - want)))
+    top = float(np.max(np.abs(want)))
+    print(f"kernel: latent paged decode B={slots} H={H} r={r} dr={dr} "
+          f"page={page} max|pallas-ref|={err!r} of max|ref|={top!r}")
+    check(kops.resolve("paged_decode_latent_attention") == "pallas",
+          "latent paged decode dispatches to the Pallas kernel")
+    check(err <= LATENT_TOL * top,
+          f"latent paged decode matches the reference ({err!r} <= "
+          f"{LATENT_TOL} x {top!r})")
+
+
 def one_chip(seed: int) -> None:
     from repro.api import Session
     from repro.kernels import ops as kops
@@ -236,6 +285,7 @@ def one_chip(seed: int) -> None:
           f"{peak_gib(dev)}")
     from repro.configs import get_config
     kernel_phase(get_config(ARCH), seed)
+    latent_kernel_phase(seed)
     print("dispatch:", json.dumps(kops.dispatch_report(), default=str))
 
 

@@ -20,6 +20,19 @@ def _pad_to(x: int, m: int) -> int:
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 configures
+    it: ``rope_scaling`` of its ``config.json``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """One assigned architecture, exactly as specified in the brief."""
 
@@ -42,6 +55,15 @@ class ModelConfig:
     local_global_pattern: int = 0   # N local per 1 global (gemma3: 5)
     act: str = "silu"               # silu (SwiGLU) | gelu (GeGLU)
     emb_scale: bool = False         # gemma multiplies embeddings by sqrt(D)
+    yarn: Optional[Yarn] = None     # YaRN-scaled rotary frequencies
+
+    # multi-head latent attention (DeepSeek-V2, arXiv:2405.04434): K and V
+    # are decompressed per head from a kv_lora_rank latent; the rotary
+    # part of the key is one shared qk_rope_head_dim vector
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # MoE
     n_experts: int = 0
@@ -50,6 +72,13 @@ class ModelConfig:
     d_ff_expert: int = 0
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    norm_topk_prob: bool = True     # renormalise the top-k gate weights
+    first_k_dense: int = 0          # leading dense layers (width d_ff)
+    # one chip's share of expert parallelism for the dropless serve layer
+    # (moe.forward_held): (shard, n_shards) holds experts
+    # [shard*E/n, (shard+1)*E/n) of the n_experts the router scores;
+    # (0, 0) holds them all
+    expert_shard: Tuple[int, int] = (0, 0)
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -67,6 +96,10 @@ class ModelConfig:
 
     norm_eps: float = 1e-6
     source: str = ""                # provenance note from the brief
+
+    def __post_init__(self):
+        # a shard read from JSON arrives as a list; keep the config hashable
+        object.__setattr__(self, "expert_shard", tuple(self.expert_shard))
 
     # -- derived -------------------------------------------------------------
     @property
@@ -91,6 +124,27 @@ class ModelConfig:
     @property
     def d_shared_ff(self) -> int:
         return self.n_shared_experts * self.d_ff_expert
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Width of one latent cache row: the normalised c_kv and the
+        roped shared key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def n_held_experts(self) -> int:
+        """Experts this chip holds (all of them without a shard)."""
+        n = self.expert_shard[1]
+        return self.n_experts // n if n else self.n_experts
+
+    @property
+    def held_first(self) -> int:
+        """Index of the first held expert."""
+        return self.expert_shard[0] * self.n_held_experts
 
     def has_attention(self) -> bool:
         return self.family != "ssm"

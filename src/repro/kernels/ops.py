@@ -242,6 +242,21 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens,
                                        seq_lens, layer, scale=scale)
 
 
+def paged_decode_latent_attention(q, c_pages, pe_pages, block_table,
+                                  seq_lens, layer, *, scale):
+    """Latent (MLA) decode attention of one layer of the stacked latent
+    pool (see
+    :func:`repro.kernels.paged_attention.paged_decode_latent_attention`):
+    the Pallas kernel wherever Pallas runs, the oracle elsewhere."""
+    mode = resolve("paged_decode_latent_attention")
+    if mode in ("pallas", "interpret"):
+        return _paged.paged_decode_latent_attention(
+            q, c_pages, pe_pages, block_table, seq_lens, layer, scale=scale,
+            interpret=(mode == "interpret"))
+    return _ref.paged_decode_latent_attention(
+        q, c_pages, pe_pages, block_table, seq_lens, layer, scale=scale)
+
+
 # --------------------------------------------------------------------------
 # Dequant-fused GEMM epilogue
 # --------------------------------------------------------------------------

@@ -138,3 +138,127 @@ def paged_decode_attention(
     h = jnp.arange(Hkv)
     out = out.reshape(B, Hkv, g, Hkv, hd)[:, h, :, h]     # (Hkv, B, g, hd)
     return out.transpose(1, 0, 2, 3).reshape(B, Hq, hd).astype(q.dtype)
+
+
+# --------------------------------------------------------------------------
+# Latent (MLA) paged decode: one latent row per position, shared by heads
+# --------------------------------------------------------------------------
+
+def rope_pack(rope_dim: int) -> int:
+    """Positions whose rope keys share one row of the rope pool: as many
+    as fill 128 lanes (2 at DeepSeek-V2's 64)."""
+    return max(1, 128 // rope_dim)
+
+
+def _paged_latent_kernel(tbl_ref, len_ref, layer_ref, qc_ref, qpe_ref,
+                         c_ref, pe_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                         page: int, n_pages: int, pack: int, heads: int):
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # pages past the last live one repeat its block index (no fetch) and
+    # skip the math
+    @pl.when(j * page < len_ref[b])
+    def _page():
+        c = c_ref[0]                                       # (page, r)
+        nt = (((1,), (1,)), ((), ()))
+        s = jax.lax.dot_general(qc_ref[0], c, nt,
+                                preferred_element_type=jnp.float32)
+        # the block-diagonal rope query scores every packed column group
+        # at once: row group k holds the positions k*page/pack + i
+        spe = jax.lax.dot_general(qpe_ref[0], pe_ref[0], nt,
+                                  preferred_element_type=jnp.float32)
+        s = s + jnp.concatenate(
+            [spe[k * heads:(k + 1) * heads] for k in range(pack)], axis=1)
+        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+        s = jnp.where(kpos < len_ref[b], s, -jnp.inf)      # (H, page)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(c.dtype), c, preferred_element_type=jnp.float32)
+
+    @pl.when(j == n_pages - 1)
+    def _flush():
+        o_ref[0] = acc_ref[...] / l_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_decode_latent_attention(
+    q: jax.Array,             # (B, H, r + dr) absorbed query per sequence
+    c_pages: jax.Array,       # (L, P, page, r) the stacked c_kv pool
+    pe_pages: jax.Array,      # (L, P, page // pack, pack * dr) rope keys
+    block_table: jax.Array,   # (B, n_pages) int32 — physical page per slot
+    seq_lens: jax.Array,      # (B,) int32 — live length (pos + 1), >= 1
+    layer: jax.Array,         # int32 scalar — the layer of the pool to read
+    *,
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """MQA of every head's absorbed query over the sequence's latent rows:
+    each page's ``c_kv`` block serves as key (with its rope block) and as
+    value, read once for all heads.  Position ``p`` of a page keeps its
+    rope key in row ``p % (page // pack)``, column group ``p // (page //
+    pack)`` of the page's rope block, so both pools are exact multiples
+    of 128 lanes (a 576-wide row would pad to 640).  The grid ``(B,
+    n_pages)`` clamps the page index to the slot's last live page, so
+    dead pages cost a grid step but no HBM read.  Returns ``(B, H, r)``
+    float32."""
+    B, H, _ = q.shape
+    page, r = c_pages.shape[2:]
+    rows, wpe = pe_pages.shape[2:]
+    pack = page // rows
+    dr = wpe // pack
+    n_pages = block_table.shape[1]
+    qs = q.astype(jnp.float32) * scale
+    qc = qs[..., :r].astype(c_pages.dtype)
+    # (B, pack*H, pack*dr): row group k holds q_pe in column group k
+    eye = jnp.eye(pack, dtype=jnp.float32)
+    qpe = jnp.einsum("bhd,kj->bkhjd", qs[..., r:], eye).reshape(
+        B, pack * H, wpe).astype(pe_pages.dtype)
+
+    def page_index(b, j, tbl, lens, lyr):
+        last = (lens[b] - 1) // page
+        return (lyr[0], tbl[b, jnp.minimum(j, last)], 0, 0)
+
+    slot = lambda b, j, tbl, lens, lyr: (b, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, n_pages),
+        in_specs=[
+            pl.BlockSpec((1, H, r), slot),
+            pl.BlockSpec((1, pack * H, wpe), slot),
+            pl.BlockSpec((None, 1, page, r), page_index),
+            pl.BlockSpec((None, 1, rows, wpe), page_index),
+        ],
+        out_specs=pl.BlockSpec((1, H, r), slot),
+        scratch_shapes=[
+            pltpu.VMEM((H, 1), jnp.float32),      # running max
+            pltpu.VMEM((H, 1), jnp.float32),      # running denominator
+            pltpu.VMEM((H, r), jnp.float32),      # output accumulator
+        ],
+    )
+    assert dr * pack == wpe and rows * pack == page, (
+        c_pages.shape, pe_pages.shape)
+    return pl.pallas_call(
+        functools.partial(_paged_latent_kernel, page=page, n_pages=n_pages,
+                          pack=pack, heads=H),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, r), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+        name="dmath_paged_decode_latent",
+    )(block_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), qc, qpe, c_pages,
+      pe_pages)

@@ -152,6 +152,45 @@ def paged_decode_attention(
     return out.reshape(B, Hq, hd).astype(q.dtype)
 
 
+def unpack_rope_rows(pe: jax.Array, page: int) -> jax.Array:
+    """Rope keys of ``(..., page // pack, pack * dr)`` rope blocks in
+    position order, ``(..., page, dr)``: position ``p`` lives in row
+    ``p % (page // pack)``, column group ``p // (page // pack)``."""
+    rows, w = pe.shape[-2:]
+    pack = page // rows
+    pe = pe.reshape(pe.shape[:-1] + (pack, w // pack))
+    return jnp.swapaxes(pe, -3, -2).reshape(pe.shape[:-3] + (page,
+                                                             w // pack))
+
+
+def paged_decode_latent_attention(
+    q: jax.Array,             # (B, H, r + dr) absorbed query per sequence
+    c_pages: jax.Array,       # (L, P, page, r) the stacked c_kv pool
+    pe_pages: jax.Array,      # (L, P, page // pack, pack * dr) rope keys
+    block_table: jax.Array,   # (B, n_pages) int32
+    seq_lens: jax.Array,      # (B,) int32 — live length (pos + 1)
+    layer: jax.Array,         # int32 scalar — the layer of the pool to read
+    *,
+    scale: float,
+) -> jax.Array:
+    """Gather-then-attend definition of the latent paged decode kernel:
+    every head scores ``[q_c | q_pe]`` against the sequence's latent rows
+    ``[c_kv | k_pe]`` and mixes their ``c_kv``.  (B, H, r) float32."""
+    B = q.shape[0]
+    page, r = c_pages.shape[2:]
+    T = block_table.shape[1] * page
+    c = c_pages[layer, block_table].reshape(B, T, r).astype(jnp.float32)
+    pe = unpack_rope_rows(pe_pages[layer, block_table], page)
+    pe = pe.reshape(B, T, -1).astype(jnp.float32)
+    qf = q.astype(jnp.float32) * scale
+    s = jnp.einsum("bhr,btr->bht", qf[..., :r], c) \
+        + jnp.einsum("bhd,btd->bht", qf[..., r:], pe)
+    mask = jnp.arange(T)[None, :] < seq_lens[:, None]
+    s = jnp.where(mask[:, None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bht,btr->bhr", p, c)
+
+
 # --------------------------------------------------------------------------
 # Mamba2 SSD (state-space duality) — chunked scan semantics
 # --------------------------------------------------------------------------

@@ -52,7 +52,7 @@ def rotary(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 def flash_attention_jnp(
     q: jax.Array,                 # (B, Hq, S, D)
     k: jax.Array,                 # (B, Hkv, T, D)
-    v: jax.Array,                 # (B, Hkv, T, D)
+    v: jax.Array,                 # (B, Hkv, T, Dv)
     *,
     causal: bool = True,
     window: Optional[Union[int, jax.Array]] = None,
@@ -70,6 +70,7 @@ def flash_attention_jnp(
     """
     B, Hq, S, D = q.shape
     _, Hkv, T, _ = k.shape
+    Dv = v.shape[-1]
     g = Hq // Hkv
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     bq = min(bq, S)
@@ -91,7 +92,7 @@ def flash_attention_jnp(
     qf = q.astype(jnp.float32) * scale
     qf = qf.reshape(B, Hkv, g, nq, bq, D)
     kc = jnp.moveaxis(k.reshape(B, Hkv, nk, bkv, D), 2, 0)   # (nk, B,Hkv,bkv,D)
-    vc = jnp.moveaxis(v.reshape(B, Hkv, nk, bkv, D), 2, 0)
+    vc = jnp.moveaxis(v.reshape(B, Hkv, nk, bkv, Dv), 2, 0)
 
     kpos_base = jnp.arange(bkv)
 
@@ -125,13 +126,13 @@ def flash_attention_jnp(
 
         m0 = jnp.full((B, Hkv, g, bq, 1), NEG, jnp.float32)
         l0 = jnp.zeros((B, Hkv, g, bq, 1), jnp.float32)
-        a0 = jnp.zeros((B, Hkv, g, bq, D), jnp.float32)
+        a0 = jnp.zeros((B, Hkv, g, bq, Dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
             kv_step, (m0, l0, a0), (jnp.arange(nk), kc, vc))
         return acc / jnp.where(l == 0.0, 1.0, l)
 
     out = jax.lax.map(q_block, (jnp.arange(nq), jnp.moveaxis(qf, 3, 0)))
-    out = jnp.moveaxis(out, 0, 3).reshape(B, Hq, S, D)       # (B,Hq,S,D)
+    out = jnp.moveaxis(out, 0, 3).reshape(B, Hq, S, Dv)      # (B,Hq,S,Dv)
     if S != q_valid:
         out = out[:, :, :q_valid, :]
     return out.astype(q.dtype)

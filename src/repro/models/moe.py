@@ -38,13 +38,15 @@ from repro.models.params import ParamSpec
 
 
 def moe_specs(cfg, plan: ParallelPlan, mesh) -> Dict[str, ParamSpec]:
+    # the router scores all n_experts; the bank holds this chip's share
     D, E, Fe = cfg.d_model, cfg.n_experts, cfg.d_ff_expert
+    Eh = cfg.n_held_experts
     s = {
         "router": ParamSpec((D, E), plan.router((D, E), mesh),
                             dtype=jnp.float32),
-        "w_gate": ParamSpec((E, D, Fe), plan.experts((E, D, Fe), mesh)),
-        "w_in": ParamSpec((E, D, Fe), plan.experts((E, D, Fe), mesh)),
-        "w_out": ParamSpec((E, Fe, D), plan.experts((E, Fe, D), mesh),
+        "w_gate": ParamSpec((Eh, D, Fe), plan.experts((Eh, D, Fe), mesh)),
+        "w_in": ParamSpec((Eh, D, Fe), plan.experts((Eh, D, Fe), mesh)),
+        "w_out": ParamSpec((Eh, Fe, D), plan.experts((Eh, Fe, D), mesh),
                            init="scaled",
                            scale=0.02 / max(1, 2 * cfg.n_layers) ** 0.5),
     }
@@ -99,8 +101,9 @@ def forward(
         logits = (t.astype(jnp.float32) @ router_w)             # (T, E)
         probs = jax.nn.softmax(logits, axis=-1)
         gate_vals, gate_idx = jax.lax.top_k(probs, K)           # (T, K)
-        gate_vals = gate_vals / jnp.maximum(
-            jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
+        if cfg.norm_topk_prob:
+            gate_vals = gate_vals / jnp.maximum(
+                jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
 
         # aux loss: mean prob per expert * fraction routed per expert
         frac = jnp.mean(
@@ -168,3 +171,39 @@ def forward(
             h_layout=Layout((plan.batch_axes, None, plan.tp_axis)))
         y = y + shared
     return y, aux
+
+
+def forward_held(x: jax.Array, p: dict, cfg, *, policy
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Dropless expert layer of one chip's share (``cfg.expert_shard``).
+
+    Routes every token over all ``n_experts`` (softmax gate, top-k, the
+    weights renormalised only when ``cfg.norm_topk_prob``) and computes
+    the part of the result that the held experts give, plus the shared
+    experts.  Each held expert takes all T tokens of the step, weighted by
+    the gate (0 where the token did not choose it): no capacity, no drops,
+    and the same work whatever the routing.  What the other chips' experts
+    would add is left out; on one chip there is no exchange.
+
+    Returns (y (B, S, D), tokens routed to each held expert (n_held,)
+    int32)."""
+    B, S, D = x.shape
+    t = x.reshape(B * S, D)
+    probs = jax.nn.softmax(t.astype(jnp.float32) @ p["router"], axis=-1)
+    vals, idx = jax.lax.top_k(probs, cfg.top_k)                 # (T, K)
+    if cfg.norm_topk_prob:
+        vals = vals / jnp.maximum(jnp.sum(vals, -1, keepdims=True), 1e-9)
+    held = cfg.held_first + jnp.arange(cfg.n_held_experts)
+    chose = idx[:, :, None] == held                             # (T, K, Eh)
+    w = jnp.sum(jnp.where(chose, vals[:, :, None], 0.0), axis=1)  # (T, Eh)
+    counts = jnp.sum(chose, axis=(0, 1), dtype=jnp.int32)
+
+    g = precision.einsum("td,edf->etf", t, p["w_gate"], policy=policy)
+    h = precision.einsum("td,edf->etf", t, p["w_in"], policy=policy)
+    h = layers.act_fn(cfg.act)(g) * h * w.T[:, :, None]
+    y = precision.einsum("etf,efd->td", h.astype(x.dtype), p["w_out"],
+                         policy=policy).reshape(B, S, D)
+    if cfg.n_shared_experts:
+        y = y + layers.glu_mlp(x, p["shared_gate"], p["shared_in"],
+                               p["shared_out"], act=cfg.act, policy=policy)
+    return y.astype(x.dtype), counts

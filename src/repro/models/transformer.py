@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from repro.core import precision
 from repro.core.layout import Layout, constrain
 from repro.core.planner import ParallelPlan, plan_for
-from repro.models import attention, layers, moe, ssm
+from repro.models import attention, layers, mla, moe, ssm
 from repro.models.params import (ParamSpec, tree_init, tree_layouts,
                                  tree_sds, tree_shardings)
 
@@ -50,7 +50,9 @@ class Model:
     # ------------------------------------------------------------------
     # parameter specs
     # ------------------------------------------------------------------
-    def _layer_specs(self) -> Dict[str, Any]:
+    def _layer_specs(self, dense: bool = False) -> Dict[str, Any]:
+        """One layer's specs; ``dense`` forces the gated MLP of width
+        ``d_ff`` (the leading dense layers of an MoE stack)."""
         cfg, plan, mesh = self.cfg, self.plan, self.mesh
         D = cfg.d_model
         out_scale = 0.02 / max(1, 2 * cfg.n_layers) ** 0.5
@@ -58,8 +60,9 @@ class Model:
         if cfg.family in ("dense", "moe", "audio", "vlm"):
             s["ln1"] = ParamSpec((D,), plan.vector((D,), mesh), init="ones")
             s["ln2"] = ParamSpec((D,), plan.vector((D,), mesh), init="ones")
-            s["attn"] = attention.attn_specs(cfg, plan, mesh)
-            if cfg.family == "moe":
+            s["attn"] = (mla.mla_specs(cfg, plan, mesh) if cfg.is_mla
+                         else attention.attn_specs(cfg, plan, mesh))
+            if cfg.family == "moe" and not dense:
                 s["moe"] = moe.moe_specs(cfg, plan, mesh)
             else:
                 F = cfg.d_ff
@@ -83,10 +86,13 @@ class Model:
             "final_norm": ParamSpec((D,), plan.vector((D,), mesh),
                                     init="ones"),
         }
-        layer = self._layer_specs()
-        specs["layers"] = jax.tree.map(
-            lambda sp: sp.stacked(cfg.n_layers), layer,
+        stack = lambda tree, n: jax.tree.map(
+            lambda sp: sp.stacked(n), tree,
             is_leaf=lambda x: isinstance(x, ParamSpec))
+        k = cfg.first_k_dense
+        specs["layers"] = stack(self._layer_specs(), cfg.n_layers - k)
+        if k:
+            specs["dense_layers"] = stack(self._layer_specs(dense=True), k)
         if cfg.family == "hybrid":
             # the zamba2 shared transformer block (one set of weights,
             # applied every cfg.attn_every layers)
@@ -240,6 +246,11 @@ class Model:
     def forward(self, params, tokens, vision_embeds=None,
                 with_cache: bool = False, last_only: bool = False):
         cfg, plan = self.cfg, self.plan
+        if cfg.is_mla or cfg.first_k_dense:
+            raise NotImplementedError(
+                f"{cfg.name}: latent attention and leading dense layers "
+                "run on the paged serve path only (prefill_chunk_paged, "
+                "decode_step_paged)")
         x = self._embed(params, tokens, vision_embeds)
         B, S, _ = x.shape
         windows = self._window_array(S)
@@ -448,13 +459,90 @@ class Model:
         assert self.paged_supported(), (
             f"paged decode unsupported for family={cfg.family!r} "
             f"window={cfg.window} softcap={cfg.attn_softcap}")
-        # (L, P, page, Hkv*hd): the heads merged into the minor dim, the
-        # block the decode kernel streams, so every layer reads and writes
-        # the pool where it lies
-        shape = (cfg.n_layers, num_pages, page_size,
-                 cfg.n_kv_heads * cfg.d_head)
-        return {"k_pages": jnp.zeros(shape, jnp.bfloat16),
-                "v_pages": jnp.zeros(shape, jnp.bfloat16)}
+        L = cfg.n_layers
+        if cfg.is_mla:
+            # one latent row [c_kv | k_pe] per position, shared by heads
+            # (repro.models.mla): c_kv rows, and the rope keys packed
+            # ``pack`` positions to a 128-lane row
+            from repro.kernels.paged_attention import rope_pack
+            pack = rope_pack(cfg.qk_rope_head_dim)
+            if page_size % pack:
+                raise ValueError(f"page_size {page_size} must be a multiple "
+                                 f"of the rope pack {pack}")
+            pool = {
+                "latent_pages": jnp.zeros(
+                    (L, num_pages, page_size, cfg.kv_lora_rank),
+                    jnp.bfloat16),
+                "rope_pages": jnp.zeros(
+                    (L, num_pages, page_size // pack,
+                     pack * cfg.qk_rope_head_dim), jnp.bfloat16)}
+        else:
+            # (L, P, page, Hkv*hd): the heads merged into the minor dim,
+            # the block the decode kernel streams, so every layer reads
+            # and writes the pool where it lies
+            shape = (L, num_pages, page_size, cfg.n_kv_heads * cfg.d_head)
+            pool = {"k_pages": jnp.zeros(shape, jnp.bfloat16),
+                    "v_pages": jnp.zeros(shape, jnp.bfloat16)}
+        if self.holds_experts:
+            # decode steps add the tokens routed to each held expert of
+            # each MoE layer here, on the device
+            pool["held_tokens"] = jnp.zeros(
+                (L - cfg.first_k_dense, cfg.n_held_experts), jnp.int32)
+        return pool
+
+    @property
+    def holds_experts(self) -> bool:
+        """Serve MoE layers with the dropless held layer: a latent-attention
+        config, or one that holds a share of the experts."""
+        cfg = self.cfg
+        return cfg.family == "moe" and (cfg.is_mla or cfg.expert_shard[1] > 0)
+
+    def _paged_stack(self, params, x, pool, attend):
+        """The layers of a paged serve step over the page pool in the scan
+        carry: the leading dense layers (``cfg.first_k_dense``), then the
+        rest.  ``attend(h, attn_params, pool, layer)`` is one layer's
+        attention and returns (out, pool).  MoE layers take the dropless
+        held expert layer (:func:`repro.models.moe.forward_held`) where
+        :attr:`holds_experts`, else the capacity layer.  Returns (x, pool,
+        tokens routed to each held expert per MoE layer, or None)."""
+        cfg = self.cfg
+        k = cfg.first_k_dense
+
+        def mlp(h, lp):
+            m = lp["mlp"]
+            return layers.glu_mlp(h, m["gate"], m["in"], m["out"],
+                                  act=cfg.act, policy=self.policy), None
+
+        def routed(h, lp):
+            if self.holds_experts:
+                return moe.forward_held(h, lp["moe"], cfg, policy=self.policy)
+            f, _ = moe.forward(h, lp["moe"], cfg, self.plan, self.mesh,
+                               policy=self.policy)
+            return f, None
+
+        def scan(x, pool, stacked, lo, hi, ffn):
+            def body(carry, xs):
+                x, pool = carry
+                lp, i = xs
+                h = layers.rms_norm(x, lp["ln1"], cfg.norm_eps)
+                a, pool = attend(h, lp["attn"], pool, i)
+                x = x + a
+                f, held = ffn(layers.rms_norm(x, lp["ln2"], cfg.norm_eps), lp)
+                return (x + f, pool), held
+
+            return jax.lax.scan(body, (x, pool),
+                                (stacked, jnp.arange(lo, hi)))
+
+        if k:
+            (x, pool), _ = scan(x, pool, params["dense_layers"], 0, k, mlp)
+        (x, pool), held = scan(x, pool, params["layers"], k, cfg.n_layers,
+                               routed if cfg.family == "moe" else mlp)
+        return x, pool, held
+
+    def _paged_pool(self, cache):
+        names = (("latent_pages", "rope_pages") if self.cfg.is_mla
+                 else ("k_pages", "v_pages"))
+        return names, tuple(cache[n] for n in names)
 
     def prefill_chunk_paged(self, params, cache, tokens, table_row, start):
         """One fixed-size prefill chunk for ONE sequence (B=1 forward).
@@ -470,63 +558,47 @@ class Model:
         cfg, plan = self.cfg, self.plan
         x = layers.embed(tokens, params["embed"], scale=cfg.emb_scale)
         x = x.astype(jnp.bfloat16)
+        kw = dict(policy=self.policy, q_chunk=self.q_chunk,
+                  kv_chunk=self.kv_chunk)
 
-        def body(carry, xs):
-            x, kp, vp = carry
-            lp, i = xs
-            h = layers.rms_norm(x, lp["ln1"], cfg.norm_eps)
+        def attend(h, ap, pool, i):
+            if cfg.is_mla:
+                return mla.prefill_chunk_paged(h, ap, cfg, pool, i,
+                                               table_row, start, **kw)
             a, kp, vp = attention.prefill_chunk_paged(
-                h, lp["attn"], cfg, plan, kp, vp, i, table_row, start,
-                policy=self.policy, q_chunk=self.q_chunk,
-                kv_chunk=self.kv_chunk)
-            x = x + a
-            h = layers.rms_norm(x, lp["ln2"], cfg.norm_eps)
-            if cfg.family == "moe":
-                f, _ = moe.forward(h, lp["moe"], cfg, plan, self.mesh,
-                                   policy=self.policy)
-            else:
-                f = layers.glu_mlp(
-                    h, lp["mlp"]["gate"], lp["mlp"]["in"],
-                    lp["mlp"]["out"], act=cfg.act, policy=self.policy)
-            return (x + f, kp, vp), None
+                h, ap, cfg, plan, *pool, i, table_row, start, **kw)
+            return a, (kp, vp)
 
-        (x, k_new, v_new), _ = jax.lax.scan(
-            body, (x, cache["k_pages"], cache["v_pages"]),
-            (params["layers"], jnp.arange(cfg.n_layers)))
-        cache = dict(cache, k_pages=k_new, v_pages=v_new)
+        names, pool = self._paged_pool(cache)
+        x, pool, _ = self._paged_stack(params, x, pool, attend)
+        cache = dict(cache, **dict(zip(names, pool)))
         logits = self._head(params, x)
         return logits, cache
 
     def decode_step_paged(self, params, cache, tokens, pos):
         """One-token serve step against the paged cache.  Same contract as
-        :meth:`decode_step` with ``cache`` from :meth:`init_paged_cache`."""
+        :meth:`decode_step` with ``cache`` from :meth:`init_paged_cache`.
+        Where the cache holds ``held_tokens`` (a held expert share), the
+        step adds the tokens it routed to each held expert of each MoE
+        layer to it, on the device."""
         cfg, plan = self.cfg, self.plan
         x = layers.embed(tokens, params["embed"], scale=cfg.emb_scale)
         x = x.astype(jnp.bfloat16)
         table = cache["table"]
 
-        def body(carry, xs):
-            x, kp, vp = carry
-            lp, i = xs
-            h = layers.rms_norm(x, lp["ln1"], cfg.norm_eps)
+        def attend(h, ap, pool, i):
+            if cfg.is_mla:
+                return mla.decode_paged(h, ap, cfg, pool, i, table, pos,
+                                        policy=self.policy)
             a, kp, vp = attention.decode_paged(
-                h, lp["attn"], cfg, plan, kp, vp, i, table, pos,
-                policy=self.policy)
-            x = x + a
-            h = layers.rms_norm(x, lp["ln2"], cfg.norm_eps)
-            if cfg.family == "moe":
-                f, _ = moe.forward(h, lp["moe"], cfg, plan, self.mesh,
-                                   policy=self.policy)
-            else:
-                f = layers.glu_mlp(
-                    h, lp["mlp"]["gate"], lp["mlp"]["in"],
-                    lp["mlp"]["out"], act=cfg.act, policy=self.policy)
-            return (x + f, kp, vp), None
+                h, ap, cfg, plan, *pool, i, table, pos, policy=self.policy)
+            return a, (kp, vp)
 
-        (x, k_new, v_new), _ = jax.lax.scan(
-            body, (x, cache["k_pages"], cache["v_pages"]),
-            (params["layers"], jnp.arange(cfg.n_layers)))
-        cache = dict(cache, k_pages=k_new, v_pages=v_new)
+        names, pool = self._paged_pool(cache)
+        x, pool, held = self._paged_stack(params, x, pool, attend)
+        cache = dict(cache, **dict(zip(names, pool)))
+        if held is not None:
+            cache["held_tokens"] = cache["held_tokens"] + held
         logits = self._head(params, x)
         return logits, cache
 

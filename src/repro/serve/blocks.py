@@ -37,7 +37,11 @@ GIB = 1024 ** 3
 
 def kv_bytes_per_block(cfg, page_size: int, dtype_bytes: int = 2) -> int:
     """Global bytes one physical page costs across the layer stack:
-    K and V, all layers, ``page_size`` positions of (Hkv, hd) heads."""
+    K and V, all layers, ``page_size`` positions of (Hkv, hd) heads; or,
+    for latent attention, one latent row of ``cfg.latent_width`` per
+    position and layer."""
+    if cfg.is_mla:
+        return cfg.n_layers * page_size * cfg.latent_width * dtype_bytes
     return (2 * cfg.n_layers * page_size * cfg.n_kv_heads * cfg.d_head
             * dtype_bytes)
 

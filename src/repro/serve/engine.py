@@ -376,6 +376,14 @@ class ContinuousEngine:
     def submit(self, req: Request):
         self.sched.submit(req)
 
+    def held_tokens(self) -> Optional[np.ndarray]:
+        """Tokens the decode steps have routed to each held expert of each
+        MoE layer, ``(MoE layers, held experts)``, read back from the
+        device counter the steps keep in the cache; None where the model
+        holds no expert share.  Waits for the work in flight."""
+        held = self.cache.get("held_tokens")
+        return None if held is None else np.asarray(held, np.int64)
+
     def _publish_cache(self):
         if self._registry is not None and self._cache_key is not None:
             self._registry.replace_value(self._cache_key, self.cache)

@@ -14,6 +14,7 @@ import this file.  Keep these tests in this one file.
 
 from __future__ import annotations
 
+import functools
 import os
 import re
 
@@ -173,3 +174,111 @@ def test_quantize_int8_compiles_for_v5e(one_chip):
         _sds((BUCKET,), jnp.float32, one_chip),
         _sds((), jnp.float32, one_chip)).compile()
     _assert_kernel(compiled)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (DeepSeek-V2): the kernel at published widths, and the
+# model steps touching the latent pool in place
+# ---------------------------------------------------------------------------
+
+#: DeepSeek-V2-Lite: 16 heads over a 512 + 64 latent; 256-position pages
+MLA_H, MLA_R, MLA_DR, MLA_PAGE = 16, 512, 64, 256
+
+
+def test_paged_decode_latent_compiles_for_v5e(one_chip):
+    batch, n_row = 32, 32
+    pages = 1 + batch * n_row
+    f = jax.jit(functools.partial(
+        paged_attention.paged_decode_latent_attention, scale=0.1147))
+    compiled = f.lower(
+        _sds((batch, MLA_H, MLA_R + MLA_DR), jnp.float32, one_chip),
+        _sds((27, pages, MLA_PAGE, MLA_R), jnp.bfloat16, one_chip),
+        _sds((27, pages, MLA_PAGE // 2, 2 * MLA_DR), jnp.bfloat16,
+             one_chip),
+        _sds((batch, n_row), jnp.int32, one_chip),
+        _sds((batch,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip)).compile()
+    _assert_kernel(compiled)
+    # the pool is read where it lies: no temporary near its size
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def _mla_model(one_chip, monkeypatch):
+    """DeepSeek-V2-Lite's attention and expert widths at two layers (the
+    dense one and one MoE layer of 8 held experts), small vocabulary."""
+    import dataclasses
+
+    from repro.configs import get_config
+    monkeypatch.setenv("REPRO_KERNELS", "pallas")
+    monkeypatch.setattr(ops, "_PROBED", True)
+    monkeypatch.setattr(ops, "_PROBE_ERROR", None)
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), n_layers=2,
+                              vocab_size=1024, expert_shard=(0, 8))
+    mesh = make_host_mesh(devices=list(one_chip.device_set))
+    model = Model(cfg, mesh)
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    with jax.set_mesh(mesh):
+        params = shapes(jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0))))
+        pool = shapes(jax.eval_shape(
+            lambda: model.init_paged_pool(N_POOL, MLA_PAGE)))
+    return model, mesh, params, pool
+
+
+def _latent_pool_copies(hlo: str):
+    """Instructions whose result is one whole layer of either latent pool
+    array, other than parameters and bitcasts, and copies or transposes
+    of a whole stacked array."""
+    layer = {(N_POOL, MLA_PAGE, MLA_R), (N_POOL, MLA_PAGE // 2, 2 * MLA_DR)}
+    layer |= {(1,) + s for s in layer}
+    stacked = {(2,) + s for s in layer if s[0] == N_POOL}
+    bad = []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if not m or not m.group(1):
+            continue
+        dims, op = tuple(int(d) for d in m.group(1).split(",")), m.group(2)
+        if dims in layer and op not in ("parameter", "bitcast"):
+            bad.append(line.strip()[:160])
+        if dims in stacked and op in ("copy", "copy-start", "transpose"):
+            bad.append(line.strip()[:160])
+    return bad
+
+
+def test_mla_decode_step_reads_the_latent_pool_in_place(one_chip,
+                                                        monkeypatch):
+    """The absorbed decode step scatters each layer's latent rows into the
+    stacked pool and the latent kernel reads it there: no relayout or
+    copy of the pool, and no temporary near a layer's size."""
+    model, mesh, params, pool = _mla_model(one_chip, monkeypatch)
+    n_row = 8
+    cache = dict(pool, table=_sds((N_SLOTS, n_row), jnp.int32, one_chip))
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(model.decode_step_paged, donate_argnums=(1,)
+                           ).lower(params, cache,
+                                   _sds((N_SLOTS, 1), jnp.int32, one_chip),
+                                   _sds((N_SLOTS,), jnp.int32, one_chip)
+                                   ).compile()
+    _assert_kernel(compiled)
+    assert _latent_pool_copies(compiled.as_text()) == []
+    layer_bytes = N_POOL * MLA_PAGE * (MLA_R + MLA_DR) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes // 4
+
+
+def test_mla_prefill_chunk_reads_the_latent_pool_in_place(one_chip,
+                                                          monkeypatch):
+    """A prefill chunk scatters its latent rows into the stacked pool and
+    gathers back only the sequence's pages to decompress."""
+    model, mesh, params, pool = _mla_model(one_chip, monkeypatch)
+    n_row = 8
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(model.prefill_chunk_paged,
+                           donate_argnums=(1,)).lower(
+            params, pool, _sds((1, CHUNK), jnp.int32, one_chip),
+            _sds((n_row,), jnp.int32, one_chip),
+            _sds((), jnp.int32, one_chip)).compile()
+    assert _latent_pool_copies(compiled.as_text()) == []
